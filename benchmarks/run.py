@@ -9,6 +9,8 @@ import importlib
 import sys
 import time
 
+from repro.launch.cache import enable_compile_cache
+
 BENCHES = [
     "bench_selection",        # Tables II/III
     "bench_selection_time",   # Fig. 3
@@ -35,6 +37,7 @@ def main() -> None:
     if args.skip:
         names = [n for n in names if n not in set(args.skip.split(","))]
 
+    enable_compile_cache()
     print("name,value,note")
     failures = 0
     for name in names:

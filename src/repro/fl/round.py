@@ -52,7 +52,9 @@ def tree_weighted_sum(trees_stacked, weights, use_kernel: bool = False):
     Uses ``lax.dot_general`` with ``preferred_element_type=float32`` so
     accumulation happens in f32 *without* first materializing an f32
     copy of the stacked (K, P) tree (which doubled peak memory on bf16
-    deltas); weights are cast to the leaf dtype instead.
+    deltas); weights are cast to the leaf dtype instead. ``HIGHEST``
+    precision keeps f32 products in f32 on a TPU, whose default
+    multiplies them in bfloat16 (the CPU computes both alike).
     """
     if use_kernel:
         return kops.fedavg_agg_tree(trees_stacked, weights)
@@ -62,6 +64,7 @@ def tree_weighted_sum(trees_stacked, weights, use_kernel: bool = False):
         flat = leaf.reshape(K, -1)
         acc = jax.lax.dot_general(
             weights.astype(leaf.dtype), flat, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
         return acc.reshape(leaf.shape[1:]).astype(leaf.dtype)
 
@@ -132,7 +135,8 @@ def _aggregate_and_quality(deltas, w, use_agg_kernel: bool,
 
 
 def _tree_dot(a, b):
-    return sum(jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32))
+    return sum(jnp.vdot(x.astype(jnp.float32), y.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
                for x, y in zip(jax.tree_util.tree_leaves(a),
                                jax.tree_util.tree_leaves(b)))
 
@@ -345,7 +349,6 @@ def make_fl_rounds_scan_sharded(loss_fn: Callable, local_lr: float = 0.05,
     election would diverge). Fault-mode ``arrival`` masks are
     supported — they shard with the schedule.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_host_mesh
@@ -415,11 +418,11 @@ def make_fl_rounds_scan_sharded(loss_fn: Callable, local_lr: float = 0.05,
         sched_spec["round_ids"] = P()
         shard_spec = {"masks": P(None, axis), "q_values": P(None, axis),
                       "client_losses": P(None, axis), "mean_loss": P()}
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), sched_spec, P()),
             out_specs=(P(), shard_spec),
-            check_rep=False)
+            check_vma=False)
         return mapped(params, data, schedule, base_key)
 
     return chunk_fn

@@ -7,24 +7,23 @@ compressed update plane (fl.compression):
 
 - ``topk_sparsify`` — per-row magnitude top-k with index+value packing:
   each flattened client delta keeps its k largest-|x| entries (signed
-  values + lane indices). Same iterative max-extract shape as
-  ``segmented_topk`` (one grid step per row, the ``(1, P)`` row resident
-  in VMEM, k vectorized max/mask passes, frontiers carried through a
-  ``fori_loop`` and written once); ties break to the lowest lane,
-  matching ``jax.lax.top_k`` over ``|x|``.
+  values + lane indices). The selection is ``segmented_topk``'s tiled
+  max-extract (``row_topk``) over ``|x|``; ties break to the lowest
+  lane, matching ``jax.lax.top_k`` over ``|x|``.
 
 - ``quantize_i8`` / ``dequantize_i8`` — per-chunk symmetric int8: each
   ``chunk``-wide slice of a row is scaled by ``amax/127`` (f32 scales,
-  one per chunk) and rounded to int8. The grid is ``(rows, chunks)``;
-  the caller pads the parameter axis with zeros up to a chunk multiple
-  (padding quantizes to 0 and is sliced off), so no in-kernel tail
-  masking is needed and kernel == oracle bit-for-bit.
+  one per chunk) and rounded to int8. Blocks are 8 rows by
+  ``128 * chunk`` lanes, so each block's scales form one lane-dense
+  ``(8, 128)`` tile; the wrapper zero-pads rows and the parameter axis
+  to whole blocks (padding quantizes to 0 and is sliced off), so no
+  in-kernel tail masking is needed.
 
 - ``fedavg_agg_quality_i8`` — the fused *compressed* sibling of
   ``fedavg_agg_quality``: one pass over the quantized payloads
-  dequantizes in-register and emits the weighted aggregate Δ_t plus all
-  per-client Gram terms of the quality cosine — the server never
-  materializes the dequantized (K, P) matrix in HBM.
+  dequantizes each block into VMEM and emits the weighted aggregate Δ_t
+  plus all per-client Gram terms of the quality cosine — the server
+  never materializes the dequantized (K, P) matrix in HBM.
 
 Like every kernel in this package, each has a jnp oracle in ``ref.py``
 and is called through the dispatching wrappers in ``ops.py``.
@@ -36,109 +35,115 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
+from .segmented_topk import _BLOCK, _ROWS, pad_rows, row_topk
+
+_TILE_CHUNKS = 128   # chunks per lane tile: one lane-dense (8, 128) scale tile
 
 
-def _pad_to_chunks(x, chunk: int):
-    """Zero-pad the last axis up to a multiple of ``chunk``."""
-    P = x.shape[-1]
-    pp = -(-P // chunk) * chunk
-    if pp == P:
+def _first_block(i):
+    """Block (0, 0) at every grid step. Block indices are int32: a
+    Python 0 lowers to i64 under jax_enable_x64, which Mosaic rejects."""
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _column_block(i):
+    """Block (0, i): the i-th parameter block of every row."""
+    return jnp.int32(0), i
+
+
+def _chunk_tiles(rows: int, p: int, chunk: int):
+    """Tiling of a (rows, p) payload into (8, tc * chunk) lane tiles.
+
+    Returns ``(rp, nc, tc, nt)``: padded row count, chunks per row,
+    chunks per tile and tiles per row. One tile spans the whole row
+    when it has at most ``_TILE_CHUNKS`` chunks (full-array blocks are
+    always legal); longer rows are zero-padded to whole tiles.
+    """
+    rp = -(-rows // _ROWS) * _ROWS
+    nc = -(-p // chunk)
+    tc = nc if nc <= _TILE_CHUNKS else _TILE_CHUNKS
+    return rp, nc, tc, -(-nc // tc)
+
+
+def _pad2(x, rows: int, cols: int):
+    r, c = x.shape
+    if (r, c) == (rows, cols):
         return x
-    pad = [(0, 0)] * (x.ndim - 1) + [(0, pp - P)]
-    return jnp.pad(x, pad)
+    return jnp.pad(x, ((0, rows - r), (0, cols - c)))
 
 
 # ---------------------------------------------------------------------------
 # Magnitude top-k sparsification
 # ---------------------------------------------------------------------------
 
-def _topk_sparsify_kernel(x_ref, vals_ref, idx_ref, *, k: int, width: int):
-    row = x_ref[...].astype(jnp.float32)                 # (1, P)
-    mag = jnp.abs(row)
-    lanes = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-
-    def body(i, carry):
-        mag, vals, idxs = carry
-        m = jnp.max(mag, axis=1, keepdims=True)          # (1, 1)
-        # lowest lane attaining the max magnitude (stable tie-break)
-        j = jnp.min(jnp.where(mag == m, lanes, width), axis=1, keepdims=True)
-        v = jnp.sum(jnp.where(lanes == j, row, 0.0), axis=1, keepdims=True)
-        vals = jnp.where(slots == i, v, vals)
-        idxs = jnp.where(slots == i, j, idxs)
-        mag = jnp.where(lanes == j, -jnp.inf, mag)
-        return mag, vals, idxs
-
-    init = (mag, jnp.zeros((1, k), jnp.float32), jnp.zeros((1, k), jnp.int32))
-    _, vals, idxs = jax.lax.fori_loop(0, k, body, init)
-    vals_ref[...] = vals
-    idx_ref[...] = idxs
-
-
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_sparsify(x, k: int, *, interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
+def topk_sparsify(x, k: int, *, block: int = _BLOCK,
+                  interpret: bool = False):
     """x: (K, P) flattened client deltas -> ``(values (K, k) f32,
     indices (K, k) int32)``: each row's k largest-magnitude entries
     (signed values), ordered by descending |value|, ties to the lowest
-    lane — exactly ``jax.lax.top_k(|x|, k)``'s selection.
+    lane — a stable descending sort of |x|, which is what
+    ``jax.lax.top_k(|x|, k)`` returns on the CPU. (On a v5e,
+    ``lax.top_k`` over ~1M lanes returns the same set in another order.)
     """
-    K, P = x.shape
-    k = int(min(k, P))
-    return pl.pallas_call(
-        functools.partial(_topk_sparsify_kernel, k=k, width=P),
-        grid=(K,),
-        in_specs=[pl.BlockSpec((1, P), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((K, k), jnp.float32),
-                   jax.ShapeDtypeStruct((K, k), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(x.astype(jnp.float32))
+    K, _ = x.shape
+    xf = x.astype(jnp.float32)
+    _, idx = row_topk(pad_rows(jnp.abs(xf), 0.0), k, block=block,
+                      interpret=interpret)
+    idx = idx[:K]
+    return jnp.take_along_axis(xf, idx, axis=1), idx
 
 
 # ---------------------------------------------------------------------------
 # Per-chunk symmetric int8 quantization
 # ---------------------------------------------------------------------------
 
-def _quantize_i8_kernel(x_ref, v_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)                   # (1, C)
-    scale = jnp.max(jnp.abs(x)) / 127.0
-    q = jnp.where(scale > 0.0, jnp.round(x / jnp.where(scale > 0.0,
-                                                       scale, 1.0)), 0.0)
-    v_ref[...] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
-    s_ref[...] = scale.reshape(1, 1)
+def _quantize_i8_kernel(x_ref, v_ref, s_ref, *, chunk: int, tc: int):
+    lanes = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape, 1)
+    scales = jnp.zeros(s_ref.shape, jnp.float32)             # (8, tc)
+    for c in range(tc):
+        cols = slice(c * chunk, (c + 1) * chunk)
+        x = x_ref[:, cols]                                   # (8, chunk)
+        scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0
+        q = jnp.round(x / jnp.where(scale > 0.0, scale, 1.0))
+        v_ref[:, cols] = jnp.clip(q, -127.0, 127.0).astype(jnp.int8)
+        scales = jnp.where(lanes == c, scale, scales)
+    s_ref[...] = scales
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def quantize_i8(x, *, chunk: int = 256, interpret: bool = False):
     """x: (K, P) -> ``(values (K, P) int8, scales (K, ceil(P/chunk))
     f32)``. Symmetric per-chunk: scale = amax(|chunk|)/127; an all-zero
-    chunk gets scale 0 and quantizes to 0.
+    chunk gets scale 0 and quantizes to 0. Zero padding (rows to 8, the
+    parameter axis to whole tiles) quantizes to 0 and is sliced off.
     """
     K, P = x.shape
-    xp = _pad_to_chunks(x.astype(jnp.float32), chunk)
-    nc = xp.shape[1] // chunk
+    rp, nc, tc, nt = _chunk_tiles(K, P, chunk)
+    xp = _pad2(x.astype(jnp.float32), rp, nt * tc * chunk)
     vals, scales = pl.pallas_call(
-        _quantize_i8_kernel,
-        grid=(K, nc),
-        in_specs=[pl.BlockSpec((1, chunk), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
-                   pl.BlockSpec((1, 1), lambda i, j: (i, j))],
-        out_shape=[jax.ShapeDtypeStruct((K, nc * chunk), jnp.int8),
-                   jax.ShapeDtypeStruct((K, nc), jnp.float32)],
-        compiler_params=_CompilerParams(
+        functools.partial(_quantize_i8_kernel, chunk=chunk, tc=tc),
+        grid=(rp // _ROWS, nt),
+        in_specs=[pl.BlockSpec((_ROWS, tc * chunk), lambda i, j: (i, j))],
+        out_specs=[pl.BlockSpec((_ROWS, tc * chunk), lambda i, j: (i, j)),
+                   pl.BlockSpec((_ROWS, tc), lambda i, j: (i, j))],
+        out_shape=[jax.ShapeDtypeStruct(xp.shape, jnp.int8),
+                   jax.ShapeDtypeStruct((rp, nt * tc), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(xp)
-    return vals[:, :P], scales
+    return vals[:K, :P], scales[:K, :nc]
 
 
-def _dequantize_i8_kernel(v_ref, s_ref, o_ref):
-    o_ref[...] = v_ref[...].astype(jnp.float32) * s_ref[0, 0]
+def _dequantize_i8_kernel(v_ref, s_ref, o_ref, *, chunk: int, tc: int):
+    scales = s_ref[...]                                      # (8, tc)
+    for c in range(tc):
+        cols = slice(c * chunk, (c + 1) * chunk)
+        o_ref[:, cols] = (v_ref[:, cols].astype(jnp.float32)
+                          * scales[:, c:c + 1])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -147,20 +152,21 @@ def dequantize_i8(values, scales, *, chunk: int = 256,
     """Inverse of :func:`quantize_i8`: ``(K, P) int8 + (K, nc) f32 ->
     (K, P) f32`` with each chunk rescaled by its stored scale."""
     K, P = values.shape
-    vp = _pad_to_chunks(values, chunk)
-    nc = vp.shape[1] // chunk
+    rp, nc, tc, nt = _chunk_tiles(K, P, chunk)
+    vp = _pad2(values, rp, nt * tc * chunk)
+    sp = _pad2(scales.astype(jnp.float32), rp, nt * tc)
     out = pl.pallas_call(
-        _dequantize_i8_kernel,
-        grid=(K, nc),
-        in_specs=[pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((K, nc * chunk), jnp.float32),
-        compiler_params=_CompilerParams(
+        functools.partial(_dequantize_i8_kernel, chunk=chunk, tc=tc),
+        grid=(rp // _ROWS, nt),
+        in_specs=[pl.BlockSpec((_ROWS, tc * chunk), lambda i, j: (i, j)),
+                  pl.BlockSpec((_ROWS, tc), lambda i, j: (i, j))],
+        out_specs=pl.BlockSpec((_ROWS, tc * chunk), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(vp.shape, jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(vp, scales)
-    return out[:, :P]
+    )(vp, sp)
+    return out[:K, :P]
 
 
 # ---------------------------------------------------------------------------
@@ -168,16 +174,21 @@ def dequantize_i8(values, scales, *, chunk: int = 256,
 # ---------------------------------------------------------------------------
 
 def _agg_quality_i8_kernel(w_ref, v_ref, s_ref, o_ref, dots_ref, sq_ref,
-                           asq_ref):
+                           asq_ref, u_ref, *, chunk: int, tc: int):
     i = pl.program_id(0)
-    # dequantize in-register: (K, C) int8 * (K, 1) chunk scales
-    u = v_ref[...].astype(jnp.float32) * s_ref[...]
-    w = w_ref[...].astype(jnp.float32)                   # (1, K)
-    agg = jax.lax.dot(w, u, preferred_element_type=jnp.float32)  # (1, C)
-    o_ref[...] = agg[0]
-    part_dots = jax.lax.dot(u, agg.T,
-                            preferred_element_type=jnp.float32)  # (K, 1)
-    part_sq = jnp.sum(u * u, axis=1, keepdims=True)              # (K, 1)
+    # dequantize in VMEM: (rows, chunk) int8 * (rows, 1) chunk scale
+    scales = s_ref[...]                                      # (rows, tc)
+    for c in range(tc):
+        cols = slice(c * chunk, (c + 1) * chunk)
+        u_ref[:, cols] = (v_ref[:, cols].astype(jnp.float32)
+                          * scales[:, c:c + 1])
+    u = u_ref[...]                                           # (rows, tc*chunk)
+    # f32 sums on the VPU, as in fedavg_agg_quality (an f32 dot would
+    # multiply in bfloat16 on the MXU)
+    agg = jnp.sum(w_ref[...] * u, axis=0, keepdims=True)     # (1, C)
+    o_ref[...] = agg
+    part_dots = jnp.sum(u * agg, axis=1, keepdims=True)      # (rows, 1)
+    part_sq = jnp.sum(u * u, axis=1, keepdims=True)              # (rows, 1)
     part_asq = jnp.sum(agg * agg).reshape(1, 1)
 
     @pl.when(i == 0)
@@ -202,29 +213,33 @@ def fedavg_agg_quality_i8(values, scales, weights, *, chunk: int = 256,
     Returns ``(agg (P,) f32, dots (K,), sq (K,), asq ())`` — exactly
     :func:`~repro.kernels.fedavg_agg.fedavg_agg_quality` applied to
     ``dequantize_i8(values, scales)``, but the dequantized (K, P)
-    matrix never leaves registers (zero-padding of the ragged tail
-    dequantizes to 0 and cannot perturb the sums).
+    matrix never reaches HBM: each tile is dequantized into VMEM
+    (zero-padding of the ragged tail dequantizes to 0 and cannot
+    perturb the sums; padded rows carry weight 0).
     """
     K, P = values.shape
-    vp = _pad_to_chunks(values, chunk)
-    nc = vp.shape[1] // chunk
-    w2 = weights.astype(jnp.float32).reshape(1, K)
+    rp, nc, tc, nt = _chunk_tiles(K, P, chunk)
+    width = tc * chunk
+    vp = _pad2(values, rp, nt * width)
+    sp = _pad2(scales.astype(jnp.float32), rp, nt * tc)
+    w2 = _pad2(weights.astype(jnp.float32).reshape(K, 1), rp, 1)
     agg, dots, sq, asq = pl.pallas_call(
-        _agg_quality_i8_kernel,
-        grid=(nc,),
-        in_specs=[pl.BlockSpec((1, K), lambda i: (0, 0)),
-                  pl.BlockSpec((K, chunk), lambda i: (0, i)),
-                  pl.BlockSpec((K, 1), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((chunk,), lambda i: (i,)),
-                   pl.BlockSpec((K, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((K, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nc * chunk,), jnp.float32),
-                   jax.ShapeDtypeStruct((K, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((K, 1), jnp.float32),
+        functools.partial(_agg_quality_i8_kernel, chunk=chunk, tc=tc),
+        grid=(nt,),
+        in_specs=[pl.BlockSpec((rp, 1), _first_block),
+                  pl.BlockSpec((rp, width), _column_block),
+                  pl.BlockSpec((rp, tc), _column_block)],
+        out_specs=[pl.BlockSpec((1, width), _column_block),
+                   pl.BlockSpec((rp, 1), _first_block),
+                   pl.BlockSpec((rp, 1), _first_block),
+                   pl.BlockSpec((1, 1), _first_block)],
+        out_shape=[jax.ShapeDtypeStruct((1, nt * width), jnp.float32),
+                   jax.ShapeDtypeStruct((rp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((rp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((rp, width), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(w2, vp, scales)
-    return agg[:P], dots[:, 0], sq[:, 0], asq[0, 0]
+    )(w2, vp, sp)
+    return agg[0, :P], dots[:K, 0], sq[:K, 0], asq[0, 0]

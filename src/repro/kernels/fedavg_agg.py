@@ -3,8 +3,10 @@
 The paper's server-side aggregation Δ_t = Σ_k p_k · Δ_t^(k) is a
 bandwidth-bound weighted reduction over K client updates. The kernel
 tiles the flattened parameter axis into VMEM-sized blocks; the client
-axis is the in-register reduction dimension, weights live in SMEM-like
-a (1,K) block, accumulation in f32 regardless of the update dtype.
+axis is the in-register reduction dimension, weights ride in a (K, 1)
+block, accumulation in f32 regardless of the update dtype. The sums
+run on the VPU in f32: a Mosaic f32 ``dot`` multiplies in bfloat16 on
+the TPU's MXU, which put Δ_t about 0.4% off the f32 oracle on a v5e.
 
 ``fedavg_agg_quality`` is the fused aggregation + model-quality kernel
 of the device-resident round data plane: in a single pass over the
@@ -26,14 +28,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
+
+def _first_block(i):
+    """Block (0, 0) at every grid step. Block indices are int32: a
+    Python 0 lowers to i64 under jax_enable_x64, which Mosaic rejects."""
+    return jnp.int32(0), jnp.int32(0)
+
+
+def _column_block(i):
+    """Block (0, i): the i-th parameter block of every row."""
+    return jnp.int32(0), i
 
 
 def _agg_kernel(w_ref, u_ref, o_ref):
     u = u_ref[...].astype(jnp.float32)                 # (K, bp)
-    w = w_ref[...].astype(jnp.float32)                 # (1, K)
-    acc = jax.lax.dot(w, u, preferred_element_type=jnp.float32)  # (1, bp)
-    o_ref[...] = acc[0].astype(o_ref.dtype)
+    acc = jnp.sum(w_ref[...] * u, axis=0)              # (bp,) f32, VPU
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_p", "interpret"))
@@ -45,15 +55,15 @@ def fedavg_agg(updates, weights, *, block_p: int = 16_384,
     """
     K, P = updates.shape
     bp = min(block_p, P)
-    w2 = weights.reshape(1, K)
+    w2 = weights.astype(jnp.float32).reshape(K, 1)
     return pl.pallas_call(
         _agg_kernel,
         grid=(pl.cdiv(P, bp),),
-        in_specs=[pl.BlockSpec((1, K), lambda i: (0, 0)),
-                  pl.BlockSpec((K, bp), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((K, 1), _first_block),
+                  pl.BlockSpec((K, bp), _column_block)],
         out_specs=pl.BlockSpec((bp,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((P,), updates.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w2, updates)
@@ -66,11 +76,9 @@ def _agg_quality_kernel(w_ref, u_ref, o_ref, dots_ref, sq_ref, asq_ref, *,
     # column-mask the ragged tail so reductions ignore block padding
     col = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1) + i * block_p
     u = jnp.where(col < total_p, u, 0.0)
-    w = w_ref[...].astype(jnp.float32)                 # (1, K)
-    agg = jax.lax.dot(w, u, preferred_element_type=jnp.float32)  # (1, bp)
+    agg = jnp.sum(w_ref[...] * u, axis=0, keepdims=True)         # (1, bp)
     o_ref[...] = agg[0].astype(o_ref.dtype)
-    part_dots = jax.lax.dot(u, agg.T,
-                            preferred_element_type=jnp.float32)  # (K, 1)
+    part_dots = jnp.sum(u * agg, axis=1, keepdims=True)          # (K, 1)
     part_sq = jnp.sum(u * u, axis=1, keepdims=True)              # (K, 1)
     part_asq = jnp.sum(agg * agg).reshape(1, 1)
 
@@ -101,22 +109,22 @@ def fedavg_agg_quality(updates, weights, *, block_p: int = 16_384,
     """
     K, P = updates.shape
     bp = min(block_p, P)
-    w2 = weights.reshape(1, K)
+    w2 = weights.astype(jnp.float32).reshape(K, 1)
     kernel = functools.partial(_agg_quality_kernel, total_p=P, block_p=bp)
     agg, dots, sq, asq = pl.pallas_call(
         kernel,
         grid=(pl.cdiv(P, bp),),
-        in_specs=[pl.BlockSpec((1, K), lambda i: (0, 0)),
-                  pl.BlockSpec((K, bp), lambda i: (0, i))],
+        in_specs=[pl.BlockSpec((K, 1), _first_block),
+                  pl.BlockSpec((K, bp), _column_block)],
         out_specs=[pl.BlockSpec((bp,), lambda i: (i,)),
-                   pl.BlockSpec((K, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((K, 1), lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   pl.BlockSpec((K, 1), _first_block),
+                   pl.BlockSpec((K, 1), _first_block),
+                   pl.BlockSpec((1, 1), _first_block)],
         out_shape=[jax.ShapeDtypeStruct((P,), updates.dtype),
                    jax.ShapeDtypeStruct((K, 1), jnp.float32),
                    jax.ShapeDtypeStruct((K, 1), jnp.float32),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(w2, updates)

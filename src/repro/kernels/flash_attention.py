@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -2.0 ** 30
 
@@ -105,25 +106,13 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            _vmem((bq, 1), jnp.float32),   # running max m
-            _vmem((bq, 1), jnp.float32),   # running sum l
-            _vmem((bq, hd), jnp.float32),  # output accumulator
+            pltpu.VMEM((bq, 1), jnp.float32),   # running max m
+            pltpu.VMEM((bq, 1), jnp.float32),   # running sum l
+            pltpu.VMEM((bq, hd), jnp.float32),  # output accumulator
         ],
-        compiler_params=_tpu_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(q, k, v)
 
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
-
-
-def _tpu_params():
-    try:
-        from ._compat import CompilerParams
-        return CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except Exception:
-        return None

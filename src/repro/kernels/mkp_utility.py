@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 _EPS = 1e-12
 
 
@@ -33,7 +31,10 @@ def _mkp_utility_kernel(v_ref, sel_ref, w_ref, r_ref, o_ref):
     sel = sel_ref[...]                               # (bn,)   f32 0/1
     scarcity = 1.0 / jnp.maximum(resid, _EPS)        # (1, m)
     penalty = jnp.sum(w * scarcity, axis=1)          # (bn,)
-    fits = jnp.all(w <= resid + _EPS, axis=1) & (sel > 0.0)
+    # an f32 min, not jnp.all: under jax_enable_x64 a bool reduction
+    # lowers to an f64 one, which Mosaic rejects
+    fits = (jnp.min((w <= resid + _EPS).astype(jnp.float32), axis=1) > 0.0) \
+        & (sel > 0.0)
     util = v / jnp.maximum(penalty, _EPS)
     o_ref[...] = jnp.where(fits, util, -jnp.inf)
 
@@ -55,13 +56,16 @@ def mkp_utility(values, weights, residual, selectable, *,
     return pl.pallas_call(
         _mkp_utility_kernel,
         grid=(pl.cdiv(n, bn),),
+        # block indices are int32: a Python 0 lowers to i64 under
+        # jax_enable_x64, which Mosaic rejects
         in_specs=[pl.BlockSpec((bn,), lambda i: (i,)),
                   pl.BlockSpec((bn,), lambda i: (i,)),
-                  pl.BlockSpec((bn, m), lambda i: (i, 0)),
-                  pl.BlockSpec((1, m), lambda i: (0, 0))],
+                  pl.BlockSpec((bn, m), lambda i: (i, jnp.int32(0))),
+                  pl.BlockSpec((1, m),
+                               lambda i: (jnp.int32(0), jnp.int32(0)))],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(v, sel, w, r)

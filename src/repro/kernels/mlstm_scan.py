@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _gla_kernel(q_ref, k_ref, v_ref, f_ref, i_ref, o_ref,
                 s_scr, n_scr, m_scr, *, chunk: int, normalize: bool,
@@ -124,7 +122,7 @@ def mlstm_scan(q, k, v, log_f, log_i=None, *, chunk: int = 64,
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32),
                         pltpu.VMEM((dk, 1), jnp.float32),
                         pltpu.VMEM((1, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf, ff, iff)

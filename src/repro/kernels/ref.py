@@ -10,6 +10,9 @@ import jax
 import jax.numpy as jnp
 
 _NEG_INF = -2.0 ** 30
+# f32 products in the FedAvg oracles: a TPU's default matmul precision
+# multiplies f32 in bfloat16 (on the CPU the two are the same)
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
@@ -57,7 +60,7 @@ def fedavg_agg_ref(updates, weights):
     The paper's aggregation Δ_t = Σ_k p_k Δ_t^(k), f32 accumulation.
     """
     acc = jnp.einsum("kp,k->p", updates.astype(jnp.float32),
-                     weights.astype(jnp.float32))
+                     weights.astype(jnp.float32), precision=_F32)
     return acc.astype(updates.dtype)
 
 
@@ -70,10 +73,10 @@ def fedavg_agg_quality_ref(updates, weights):
     """
     u = updates.astype(jnp.float32)
     w = weights.astype(jnp.float32)
-    agg = jnp.einsum("k,kp->p", w, u)
-    dots = u @ agg
+    agg = jnp.einsum("k,kp->p", w, u, precision=_F32)
+    dots = jnp.matmul(u, agg, precision=_F32)
     sq = jnp.sum(u * u, axis=1)
-    asq = jnp.dot(agg, agg)
+    asq = jnp.dot(agg, agg, precision=_F32)
     return agg.astype(updates.dtype), dots, sq, asq
 
 
@@ -110,7 +113,9 @@ def topk_sparsify_ref(x, k: int):
     """Magnitude top-k oracle: x (K, P) -> ``(values (K, k) f32,
     indices (K, k) int32)``. Selection is ``lax.top_k(|x|, k)`` (stable
     — ties to the lowest index); values are the *signed* originals at
-    the selected indices, ordered by descending magnitude."""
+    the selected indices, ordered by descending magnitude. Exact on the
+    CPU; on a v5e, ``lax.top_k`` over ~1M lanes returns the same set in
+    another order, so compare there with a stable host sort."""
     k = int(min(k, x.shape[-1]))
     xf = x.astype(jnp.float32)
     _, idx = jax.lax.top_k(jnp.abs(xf), k)

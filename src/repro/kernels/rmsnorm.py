@@ -9,8 +9,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                  # (bm, D)
@@ -35,7 +33,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((bm, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((M, D), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xm, scale)

@@ -8,22 +8,25 @@ shard, extracted in one pass over the sharded ratio matrix. The global
 merge then runs the exact greedy over the ``S * k`` surviving
 candidates on the host (``core.engine.hierarchical_greedy_knapsack``).
 
-Kernel shape: one grid step per segment; the segment row ``(1, C)``
-lives in VMEM for the whole program, and the top-k is an iterative
-max-extract — ``k`` vectorized max/mask passes over the resident row,
-no sort network and no dynamic stores (the running ``(1, k)``
-value/index frontiers are carried through a ``fori_loop`` and written
-once). That trades ``k`` VPU passes for a single HBM read per row,
-which is the right trade for the frontier regime ``k << C``. Ties
-break toward the lowest lane index (matching ``jax.lax.top_k`` and the
-host argsort's stable order).
+Kernel shape: the rows are padded to a multiple of 8 and the lanes are
+cut into ``block``-wide tiles, so every block is ``(8, block)`` — the
+TPU's native ``(8, 128)`` tiling. One grid step per tile extracts that
+tile's top-``k`` by iterative max-extract: ``k`` vectorized max/mask
+passes over the VMEM-resident tile, no sort network and no dynamic
+stores (the running ``(8, k)`` value/lane frontiers are carried through
+a ``fori_loop`` and written once). Ties break toward the lowest lane
+(matching ``jax.lax.top_k`` and the host argsort's stable order).
 
-Rows shorter than ``C`` are padded with ``-inf`` by the caller; a
-``-inf`` frontier entry therefore means "segment exhausted" and its
-index is meaningless (the oracle and kernel both park it at lane 0).
-VMEM bounds the segment width: a ``(1, C)`` f32 row plus the iota mask
-must fit, so keep ``C`` at or below ~256k lanes (the default shard
-capacity of ``core.device_pool`` is far under this).
+When a row spans several tiles, the tiles' frontiers are laid side by
+side in tile order and the same kernel runs again over them, until one
+tile holds the row. This merge is exact, ties included: equal keys from
+one tile leave it in ascending lane order and tiles are concatenated in
+ascending lane order, so "lowest position" in the candidate row is
+"lowest lane" in the original row. ``block`` grows to twice the
+frontier when ``k`` is large, so each level at least halves the row.
+
+Rows are padded with ``-inf``; a ``-inf`` frontier entry therefore
+means "segment exhausted" and its index is meaningless.
 """
 from __future__ import annotations
 
@@ -32,49 +35,105 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
+_ROWS = 8          # f32 sublanes per vreg: the row-tile height
+_BLOCK = 16_384    # default lane-tile width (a (8, 16384) f32 tile = 512 KiB)
 
 
-def _segmented_topk_kernel(x_ref, vals_ref, idx_ref, *, k: int, width: int):
-    row = x_ref[...].astype(jnp.float32)                 # (1, C)
+def _topk_tile_kernel(x_ref, vals_ref, idx_ref, *, k: int, width: int):
+    row = x_ref[...]                                     # (8, width) f32
     lanes = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
-    slots = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (row.shape[0], k), 1)
 
     def body(i, carry):
         row, vals, idxs = carry
-        m = jnp.max(row, axis=1, keepdims=True)          # (1, 1)
+        m = jnp.max(row, axis=1, keepdims=True)          # (8, 1)
         # lowest lane attaining the max (stable tie-break)
-        j = jnp.min(jnp.where(row == m, lanes, width), axis=1, keepdims=True)
+        j = jnp.min(jnp.where(row == m, lanes, jnp.int32(width)), axis=1,
+                    keepdims=True)
         vals = jnp.where(slots == i, m, vals)
         idxs = jnp.where(slots == i, j, idxs)
         row = jnp.where(lanes == j, -jnp.inf, row)
         return row, vals, idxs
 
-    init = (row, jnp.full((1, k), -jnp.inf, jnp.float32),
-            jnp.zeros((1, k), jnp.int32))
-    _, vals, idxs = jax.lax.fori_loop(0, k, body, init)
+    init = (row, jnp.full((row.shape[0], k), -jnp.inf, jnp.float32),
+            jnp.zeros((row.shape[0], k), jnp.int32))
+    # int32 bounds (and the int32 width above) keep every index int32
+    # under jax_enable_x64
+    _, vals, idxs = jax.lax.fori_loop(jnp.int32(0), jnp.int32(k), body,
+                                      init)
     vals_ref[...] = vals
-    idx_ref[...] = idxs
+    idx_ref[...] = idxs + pl.program_id(1) * width       # tile -> row lane
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def segmented_topk(x, k: int, *, interpret: bool = False):
+def _frontier_block(i, j):
+    # tile j of row block i; the int32 zero stays int32 under
+    # jax_enable_x64 (a Python 0 lowers to i64, which Mosaic rejects)
+    return j, i, jnp.int32(0)
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def row_topk(keys, k: int, *, block: int = _BLOCK, interpret: bool = False):
+    """keys: (R, W) f32 with ``R % 8 == 0`` -> ``((R, k) keys, (R, k)
+    int32 lanes)``, descending per row, ties to the lowest lane.
+
+    Every tile and frontier is a whole number of 128-lane vregs: rows
+    are ``-inf``-padded to whole tiles, and each tile yields
+    ``_lanes(k)`` candidates (the extra ones are its next-best, so the
+    merge stays exact); the final frontier is cut back to ``k``.
+    """
+    R, W = keys.shape
+    k = int(min(k, W))
+    bw = max(block, 2 * _lanes(k))
+    nb = -(-W // bw)
+    if nb == 1:
+        bw = _lanes(W)
+    if nb * bw != W:
+        keys = jnp.pad(keys, ((0, 0), (0, nb * bw - W)),
+                       constant_values=-jnp.inf)
+    kt = min(_lanes(k), bw)
+    vals, idx = pl.pallas_call(
+        functools.partial(_topk_tile_kernel, k=kt, width=bw),
+        grid=(R // _ROWS, nb),
+        in_specs=[pl.BlockSpec((_ROWS, bw), lambda i, j: (i, j))],
+        out_specs=[pl.BlockSpec((None, _ROWS, kt), _frontier_block),
+                   pl.BlockSpec((None, _ROWS, kt), _frontier_block)],
+        out_shape=[jax.ShapeDtypeStruct((nb, R, kt), jnp.float32),
+                   jax.ShapeDtypeStruct((nb, R, kt), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(keys)
+    if nb == 1:
+        return vals[0, :, :k], idx[0, :, :k]
+    # tile frontiers side by side, in tile order, then merge them
+    cand = jnp.transpose(vals, (1, 0, 2)).reshape(R, nb * kt)
+    lanes = jnp.transpose(idx, (1, 0, 2)).reshape(R, nb * kt)
+    vals, pos = row_topk(cand, k, block=block, interpret=interpret)
+    return vals, jnp.take_along_axis(lanes, pos, axis=1)
+
+
+def pad_rows(x, fill):
+    """Pad the leading axis of a 2-D array up to a multiple of 8."""
+    r = x.shape[0]
+    rp = -(-r // _ROWS) * _ROWS
+    if rp == r:
+        return x
+    return jnp.pad(x, ((0, rp - r), (0, 0)), constant_values=fill)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
+def segmented_topk(x, k: int, *, block: int = _BLOCK,
+                   interpret: bool = False):
     """x: (S, C) per-segment rows -> ((S, k) values f32, (S, k) lane
     indices int32), descending per segment, ties to the lowest lane.
     Entries equal to ``-inf`` mean the segment ran out of finite rows.
     """
-    S, C = x.shape
-    k = int(min(k, C))
-    return pl.pallas_call(
-        functools.partial(_segmented_topk_kernel, k=k, width=C),
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, C), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda i: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((S, k), jnp.float32),
-                   jax.ShapeDtypeStruct((S, k), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel",)),
-        interpret=interpret,
-    )(x.astype(jnp.float32))
+    S, _ = x.shape
+    keys = pad_rows(x.astype(jnp.float32), -jnp.inf)
+    vals, idx = row_topk(keys, k, block=block, interpret=interpret)
+    return vals[:S], idx[:S]

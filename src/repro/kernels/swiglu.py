@@ -10,8 +10,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams as _CompilerParams
-
 
 def _swiglu_kernel(x_ref, wg_ref, wu_ref, o_ref, accg, accu, *, nk: int):
     ki = pl.program_id(2)
@@ -55,7 +53,7 @@ def swiglu(x, w_gate, w_up, *, block_m: int = 256, block_n: int = 256,
         out_shape=jax.ShapeDtypeStruct((M, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xm, w_gate, w_up)

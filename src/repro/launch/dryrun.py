@@ -89,12 +89,8 @@ def _lower_compile(fn, args, out_sh, mesh, donate=()):
 
 
 def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict in newer jax and a
-    one-element list of dicts in older versions; normalize to a dict."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``compiled.cost_analysis()``, or ``{}`` when XLA reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def _cost_record(compiled):

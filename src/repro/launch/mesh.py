@@ -23,10 +23,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     else:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
     """Single-host mesh for CPU smoke runs: all local devices on 'data'."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"), axis_types=_auto(2))
+
+
+def _auto(n: int):
+    """Auto axis types: sharding is propagated by the compiler (jax's
+    ``make_mesh`` defaults to Explicit axes, which the FedSGD dry-run
+    and the sharded round scan are not written for)."""
+    return (jax.sharding.AxisType.Auto,) * n

@@ -10,8 +10,10 @@ two dense layers).
   ``lax.reduce_window`` max-pool — the original formulation.
 - ``"fast"``: identical math, CPU-friendly lowering — the first conv
   (few input channels) via im2col patches + matmul and 2x2 max-pool via
-  a reshape + max. Forward outputs are bit-identical to "reference";
-  gradients agree up to max-pool tie-breaking and f32 reduction order.
+  a reshape + max. Forward outputs agree with "reference" to f32
+  rounding (the GEMM sums the conv taps in another order than XLA's
+  conv; about 1e-6 on logits of magnitude 1); gradients agree up to
+  max-pool tie-breaking and f32 reduction order.
   On XLA CPU the backward pass avoids SelectAndScatter, which dominates
   the reference formulation's round time (~3x faster grads).
 - ``"auto"``: "fast" off-TPU, "reference" on TPU (where the native
@@ -78,8 +80,9 @@ def _conv_direct(x, w):
 def _conv_im2col(x, w):
     """3x3 SAME conv as 9 shifted slices + one matmul (im2col).
 
-    Bit-identical to :func:`_conv_direct`; much faster on XLA CPU when
-    the input channel count is small (the GEMM replaces a skinny conv).
+    Equal to :func:`_conv_direct` up to f32 summation order; much
+    faster on XLA CPU when the input channel count is small (the GEMM
+    replaces a skinny conv).
     """
     B, H, W, Cin = x.shape
     xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))

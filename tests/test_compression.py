@@ -64,6 +64,18 @@ class TestTopkSparsifyKernel:
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
         np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
 
+    @pytest.mark.parametrize("K,P,k", [(13, 1000, 100), (3, 2000, 7),
+                                       (10, 1500, 300)])
+    def test_tiled_rows_match_lax_topk(self, K, P, k):
+        """Rows wider than one lane tile (block=128) go through the
+        tile-frontier merge; tie-heavy magnitudes keep lowest-index
+        order across tiles."""
+        x = jnp.round(jax.random.normal(rk(8), (K, P)) * 3.0)
+        vals, idx = topk_sparsify(x, k, block=128, interpret=True)
+        rvals, ridx = ref.topk_sparsify_ref(x, k)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+        np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+
     def test_tie_break_is_lowest_index(self):
         # constant-|x| rows: selection must be the first k lanes, in
         # order, with the original signs — deterministic across runs
@@ -113,6 +125,28 @@ class TestQuantizeI8Kernel:
         d = dequantize_i8(v, s, chunk=64, interpret=True)
         rd = ref.dequantize_i8_ref(v, s, 64)
         np.testing.assert_allclose(np.asarray(d), np.asarray(rd), rtol=2e-7)
+
+    @pytest.mark.parametrize("K,P", [(10, 1000), (3, 1300)])
+    def test_multi_tile_rows(self, K, P):
+        """More than 128 chunks per row: several (8, 128 * chunk) lane
+        tiles, a ragged last tile, and rows padded to 8."""
+        x = jax.random.normal(rk(9), (K, P))
+        v, s = quantize_i8(x, chunk=4, interpret=True)
+        rv, rs = ref.quantize_i8_ref(x, 4)
+        assert s.shape == rs.shape == (K, -(-P // 4))
+        np.testing.assert_allclose(np.asarray(s), np.asarray(rs), rtol=2e-7)
+        diff = np.abs(np.asarray(v, np.int32) - np.asarray(rv, np.int32))
+        assert diff.max() <= 1
+        d = dequantize_i8(rv, rs, chunk=4, interpret=True)
+        np.testing.assert_allclose(np.asarray(d),
+                                   np.asarray(ref.dequantize_i8_ref(rv, rs, 4)),
+                                   rtol=2e-7)
+        w = jax.nn.softmax(jax.random.normal(rk(10), (K,)))
+        out = fedavg_agg_quality_i8(rv, rs, w, chunk=4, interpret=True)
+        expect = ref.fedavg_agg_quality_i8_ref(rv, rs, w, 4)
+        for got, want in zip(out, expect):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=1e-4)
 
     def test_zero_chunks_are_exact(self):
         x = jnp.zeros((2, 100))

@@ -194,14 +194,18 @@ class TestDeviceVsLegacyEquivalence:
             assert h_rep[cid] == pytest.approx(d_rep[cid], abs=5e-2)
 
     def test_fast_impl_forward_bit_equal(self):
-        """The device plane's CPU lowering is bit-identical in forward."""
+        """The device plane's CPU lowering matches the reference forward
+        to f32 rounding: im2col + GEMM sums the conv taps in another
+        order than XLA's conv, so logits of magnitude ~1 may differ by a
+        few dozen ulp (measured 1.3e-6 on jax 0.9 CPU)."""
         cfg = cnn.MNIST_CNN
         params = cnn.init_params(cfg, jax.random.PRNGKey(0))
         x = jnp.asarray(np.random.default_rng(0)
                         .random((16, 28, 28, 1), dtype=np.float32))
         ref = cnn.forward(cfg, params, x, impl="reference")
         fast = cnn.forward(cfg, params, x, impl="fast")
-        np.testing.assert_array_equal(np.asarray(ref), np.asarray(fast))
+        np.testing.assert_allclose(np.asarray(fast), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
 
 
 class TestPoolLowering:

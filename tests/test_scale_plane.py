@@ -50,6 +50,23 @@ class TestSegmentedTopk:
         np.testing.assert_array_equal(np.asarray(vk), np.asarray(vo))
         np.testing.assert_array_equal(np.asarray(ik), np.asarray(io))
 
+    @pytest.mark.parametrize("S,C,k", [(3, 1000, 1), (2, 1000, 37),
+                                       (9, 700, 100), (1, 5000, 200)])
+    @pytest.mark.parametrize("ties", [False, True])
+    def test_tiled_merge_matches_oracle(self, S, C, k, ties):
+        """Rows wider than one lane tile take the tile-frontier merge
+        (several levels at block=128); ties across tiles still break to
+        the lowest lane."""
+        from repro.kernels.segmented_topk import segmented_topk
+        x = np.random.default_rng(S * C + k).normal(size=(S, C))
+        if ties:
+            x = np.round(x * 2.0)               # a few distinct values
+        x = np.asarray(x, np.float32)
+        vo, io = ref.segmented_topk_ref(x, k)
+        vk, ik = segmented_topk(x, k, block=128, interpret=True)
+        np.testing.assert_array_equal(np.asarray(vk), np.asarray(vo))
+        np.testing.assert_array_equal(np.asarray(ik), np.asarray(io))
+
     def test_ties_break_to_lowest_lane(self):
         x = np.zeros((2, 12), np.float32)
         x[0, [3, 7, 11]] = 5.0                  # three-way tie
