@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
+
 _EPS = 1e-12
 
 
@@ -236,19 +238,27 @@ def hierarchical_greedy_knapsack(pool, budget: float,
 
     Returns ``(rows, total_score, total_cost, n_valid)`` with ``rows``
     global pool rows in pick order. ``stats``, if given, is filled with
-    path/frontier/escalation counters.
+    the task's counters: ``path`` ("frontier" | "flat-fallback"),
+    ``shards``, the last pass's ``frontier`` (F) and ``candidates``,
+    and, summed over the level-1 passes, ``passes``, ``escalations``
+    and ``frontier_slots`` (S·F); ``picks`` and ``n_valid`` are those
+    of the answer. The steps run in ``stage1.mask``,
+    ``stage1.frontier`` and ``stage1.merge`` spans
+    (:mod:`repro.core.spans`).
     """
     if mirror is None:
         mirror = pool.device_mirror(shard_cap=shard_cap)
     else:
         mirror.sync(pool)
-    valid = mirror.valid_mask(thresholds)
-    counts, cost_sum = mirror.shard_stats(valid)
+    with spans.span("stage1.mask"):
+        valid = mirror.valid_mask(thresholds)
+        counts, cost_sum = mirror.shard_stats(valid)
     n_valid = int(counts.sum())
     if stats is None:
         stats = {}
     stats.update(path="frontier", frontier=0, escalations=0,
-                 candidates=0, shards=mirror.num_shards)
+                 candidates=0, shards=mirror.num_shards, passes=0,
+                 frontier_slots=0, picks=0, n_valid=n_valid)
     if n_valid == 0:
         return np.zeros(0, np.int64), 0.0, 0.0, 0
     S = mirror.num_shards
@@ -260,43 +270,54 @@ def hierarchical_greedy_knapsack(pool, budget: float,
     if k_est >= 0.5 * n_valid:
         stats["path"] = "flat-fallback"
         rows, ts, tc, n_kept = _flat_pool_greedy(pool, budget, thresholds)
+        stats.update(picks=int(rows.size), n_valid=n_kept)
         return rows, ts, tc, n_kept
     F = int(min(max_count, max(32, 1 << int(np.ceil(
         np.log2(4.0 * k_est / S + 8.0))))))
     while True:
         stats["frontier"] = F
-        vals, rows = mirror.frontier(mirror.masked_ratio(valid), F,
-                                     interpret=interpret)
-        cand = rows[np.isfinite(vals)]
-        stats["candidates"] = int(cand.size)
-        # Host-precision merge: exact greedy over the candidate set.
-        # overall_score on the gathered rows only — identical per-row
-        # values to pool.overall, without forcing the pool-wide O(n)
-        # cache rebuild after every churn event.
-        from .criteria import overall_score
-        sc = overall_score(pool.scores[cand])
-        cs = pool.costs[cand]
-        ratio = sc / np.maximum(cs, _EPS)
-        pos = np.lexsort((cand, -ratio))      # ratio desc, row asc on ties
-        cand_s, oc = cand[pos], cs[pos]
-        rem = np.subtract.accumulate(
-            np.concatenate(([budget], oc)))[:-1]
-        unaff = oc > rem
-        stopped = bool(unaff.any())
-        k = int(np.argmax(unaff)) if stopped else oc.size
-        # Escalate iff a clipped shard could still change the answer:
-        # its whole frontier fed the consumed prefix (selection + the
-        # stopping client), or the scan never stopped at all.
-        clipped = counts > F
-        if clipped.any() and F < max_count:
-            prefix = cand_s[: k + 1] if stopped else cand_s
-            contrib = np.bincount(prefix // mirror.shard_cap, minlength=S)
-            suspect = clipped & (contrib >= F) if stopped else clipped
-            if suspect.any():
-                F = min(2 * F, max_count)
-                stats["escalations"] += 1
-                continue
+        stats["passes"] += 1
+        stats["frontier_slots"] += S * F
+        with spans.span("stage1.frontier", F=F, shards=S) as sp:
+            vals, rows = mirror.frontier(mirror.masked_ratio(valid), F,
+                                         interpret=interpret)
+            cand = rows[np.isfinite(vals)]
+            stats["candidates"] = int(cand.size)
+            spans.annotate(sp, candidates=int(cand.size))
+        with spans.span("stage1.merge") as sp:
+            # Host-precision merge: exact greedy over the candidate set.
+            # overall_score on the gathered rows only — identical per-row
+            # values to pool.overall, without forcing the pool-wide O(n)
+            # cache rebuild after every churn event.
+            from .criteria import overall_score
+            sc = overall_score(pool.scores[cand])
+            cs = pool.costs[cand]
+            ratio = sc / np.maximum(cs, _EPS)
+            pos = np.lexsort((cand, -ratio))  # ratio desc, row asc on ties
+            cand_s, oc = cand[pos], cs[pos]
+            rem = np.subtract.accumulate(
+                np.concatenate(([budget], oc)))[:-1]
+            unaff = oc > rem
+            stopped = bool(unaff.any())
+            k = int(np.argmax(unaff)) if stopped else oc.size
+            # Escalate iff a clipped shard could still change the answer:
+            # its whole frontier fed the consumed prefix (selection + the
+            # stopping client), or the scan never stopped at all.
+            escalate = False
+            clipped = counts > F
+            if clipped.any() and F < max_count:
+                prefix = cand_s[: k + 1] if stopped else cand_s
+                contrib = np.bincount(prefix // mirror.shard_cap,
+                                      minlength=S)
+                suspect = clipped & (contrib >= F) if stopped else clipped
+                escalate = bool(suspect.any())
+            spans.annotate(sp, escalate=int(escalate))
+        if escalate:
+            F = min(2 * F, max_count)
+            stats["escalations"] += 1
+            continue
         chosen = cand_s[:k]
+        stats["picks"] = int(k)
         return (chosen, float(sc[pos][:k].sum()), float(oc[:k].sum()),
                 n_valid)
 
@@ -314,15 +335,28 @@ def hierarchical_greedy_knapsack_batch(pool, budgets: np.ndarray,
 
     ``thresholds_list``: per-task thresholds (or ``None``), length T.
     Returns a list of ``(rows, total_score, total_cost, n_valid)``.
+    The sync runs in a ``stage1.sync`` span, each task in a
+    ``stage1.task`` span whose arguments are its ``stats`` counters.
     """
-    if mirror is None:
-        mirror = pool.device_mirror(shard_cap=shard_cap)
-    else:
-        mirror.sync(pool)
+    with spans.span("stage1.sync"):
+        if mirror is None:
+            mirror = pool.device_mirror(shard_cap=shard_cap)
+        else:
+            mirror.sync(pool)
     budgets = np.atleast_1d(np.asarray(budgets, dtype=np.float64))
-    return [hierarchical_greedy_knapsack(pool, float(b), th, mirror=mirror,
-                                         interpret=interpret)
-            for b, th in zip(budgets, thresholds_list)]
+    batch = spans.current_batch()
+    out = []
+    for i, (b, th) in enumerate(zip(budgets, thresholds_list)):
+        stats: dict = {}
+        with spans.span("stage1.task", batch=batch, index=i) as sp:
+            out.append(hierarchical_greedy_knapsack(
+                pool, float(b), th, mirror=mirror, interpret=interpret,
+                stats=stats))
+            spans.annotate(sp, path=int(stats["path"] != "frontier"),
+                           passes=stats["passes"],
+                           escalations=stats["escalations"],
+                           picks=stats["picks"], n_valid=stats["n_valid"])
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -80,7 +80,7 @@ from typing import TYPE_CHECKING, Protocol, Sequence, runtime_checkable
 
 import numpy as np
 
-from . import engine
+from . import engine, spans
 from .criteria import nid
 from .pool import ClientPoolState
 from .scheduling import ScheduleResult, generate_subsets, random_subsets
@@ -351,19 +351,20 @@ class PaperGreedySelection(_BudgetedSelection):
                     note=f"only {n_kept} clients pass thresholds, "
                          f"need {task.n_star}"))
                 continue
-            rows = np.sort(rows)              # batch contract: pool order
-            res = SelectionResult(
-                pool.client_ids[rows].tolist(),
-                float(overall_score(pool.scores[rows]).sum()),
-                float(pool.costs[rows].sum()))
-            if len(res.selected) < task.n_star:
-                res.feasible = False
-                floor = pool.budget_floor(
-                    task.n_star, pool.threshold_mask(task.thresholds))
-                res.note = (f"budget {task.budget} selects only "
-                            f"{len(res.selected)} < n*={task.n_star} "
-                            f"clients; Eq.(11) floor is {floor:.1f}")
-            results.append(res)
+            with spans.span("stage1.result", picks=int(rows.size)):
+                rows = np.sort(rows)          # batch contract: pool order
+                res = SelectionResult(
+                    pool.client_ids[rows].tolist(),
+                    float(overall_score(pool.scores[rows]).sum()),
+                    float(pool.costs[rows].sum()))
+                if len(res.selected) < task.n_star:
+                    res.feasible = False
+                    floor = pool.budget_floor(
+                        task.n_star, pool.threshold_mask(task.thresholds))
+                    res.note = (f"budget {task.budget} selects only "
+                                f"{len(res.selected)} < n*={task.n_star} "
+                                f"clients; Eq.(11) floor is {floor:.1f}")
+                results.append(res)
         return results
 
 

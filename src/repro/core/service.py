@@ -42,7 +42,7 @@ from typing import Callable, Sequence
 
 import numpy as np
 
-from . import lifecycle
+from . import lifecycle, spans
 from .criteria import ClientProfile
 from .lifecycle import RoundLog, ServiceRunResult, TaskRequest
 from .policy import resolve_scheduling_policy, resolve_selection_policy
@@ -124,22 +124,25 @@ class FLServiceProvider:
         scheduler intake passes the tenants' own state rngs so batched
         and serial intake stay bit-identical); defaults to fresh
         ``default_rng(task.seed)`` per task, matching a fresh
-        ``lifecycle.submit``.
+        ``lifecycle.submit``. The call runs in a ``stage1.batch`` span
+        (:mod:`repro.core.spans`).
         """
         if not tasks:
             return []
-        if rngs is None:
-            rngs = [np.random.default_rng(t.seed) for t in tasks]
-        groups: dict[str, list[int]] = {}
-        for i, t in enumerate(tasks):
-            groups.setdefault(resolve_selection_policy(t).name, []).append(i)
-        results: list[SelectionResult | None] = [None] * len(tasks)
-        for name, idxs in groups.items():
-            out = resolve_selection_policy(tasks[idxs[0]]).select_batch(
-                self.pool_state, [tasks[i] for i in idxs],
-                [rngs[i] for i in idxs])
-            for i, res in zip(idxs, out):
-                results[i] = res
+        with spans.batch(len(tasks)):
+            if rngs is None:
+                rngs = [np.random.default_rng(t.seed) for t in tasks]
+            groups: dict[str, list[int]] = {}
+            for i, t in enumerate(tasks):
+                groups.setdefault(resolve_selection_policy(t).name,
+                                  []).append(i)
+            results: list[SelectionResult | None] = [None] * len(tasks)
+            for name, idxs in groups.items():
+                out = resolve_selection_policy(tasks[idxs[0]]).select_batch(
+                    self.pool_state, [tasks[i] for i in idxs],
+                    [rngs[i] for i in idxs])
+                for i, res in zip(idxs, out):
+                    results[i] = res
         return results
 
     # -- Stage 2 (one period) --------------------------------------------------
