@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.segmented_topk import method as topk_method
 from . import spans
 
 _EPS = 1e-12
@@ -278,7 +279,8 @@ def hierarchical_greedy_knapsack(pool, budget: float,
         stats["frontier"] = F
         stats["passes"] += 1
         stats["frontier_slots"] += S * F
-        with spans.span("stage1.frontier", F=F, shards=S) as sp:
+        with spans.span("stage1.frontier", F=F, shards=S,
+                        method=topk_method(F, mirror.shard_cap)) as sp:
             vals, rows = mirror.frontier(mirror.masked_ratio(valid), F,
                                          interpret=interpret)
             cand = rows[np.isfinite(vals)]

@@ -2,10 +2,10 @@
 
 ``span(name, **args)`` is a ``jax.profiler.TraceAnnotation``: while a
 profiler runs (``jax.profiler.trace``) it lands in the trace beside the
-device's operations, with ``args`` as integer stats on the event; with
-no profiler running it costs about a microsecond. Nesting gives each
-span its parent. Arguments known only when the work is done go on
-through :func:`annotate`. Every argument is a host value the caller
+device's operations, with ``args`` as integer (or string) stats on the
+event; with no profiler running it costs about a microsecond. Nesting
+gives each span its parent. Arguments known only when the work is done
+go on through :func:`annotate`. Every argument is a host value the caller
 already has: a span never waits for the device.
 
 The stage-1 spans (``stage1.*``) and what each argument says are listed
@@ -23,11 +23,11 @@ _batches = itertools.count()
 _batch = contextvars.ContextVar("stage1_batch", default=-1)
 
 
-def span(name: str, **args: int) -> TraceAnnotation:
+def span(name: str, **args: int | str) -> TraceAnnotation:
     return TraceAnnotation(name, **args)
 
 
-def annotate(s: TraceAnnotation, **args: int) -> None:
+def annotate(s: TraceAnnotation, **args: int | str) -> None:
     """Add ``args`` to an open span; nothing when no profiler runs."""
     if TraceAnnotation.is_enabled():
         s.set_metadata(**args)

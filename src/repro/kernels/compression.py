@@ -7,9 +7,11 @@ compressed update plane (fl.compression):
 
 - ``topk_sparsify`` — per-row magnitude top-k with index+value packing:
   each flattened client delta keeps its k largest-|x| entries (signed
-  values + lane indices). The selection is ``segmented_topk``'s tiled
-  max-extract (``row_topk``) over ``|x|``; ties break to the lowest
-  lane, matching ``jax.lax.top_k`` over ``|x|``.
+  values + lane indices). The selection is ``segmented_topk``'s
+  threshold select (``row_topk``: 32 counting passes find the k-th
+  largest ``|x|``, one more gathers and orders the survivors) over
+  ``|x|``; ties break to the lowest lane, matching ``jax.lax.top_k``
+  over ``|x|``.
 
 - ``quantize_i8`` / ``dequantize_i8`` — per-chunk symmetric int8: each
   ``chunk``-wide slice of a row is scaled by ``amax/127`` (f32 scales,

@@ -101,9 +101,9 @@ def mlstm_scan_ref(q, k, v, log_f, log_i, *, chunk: int = 64,
 def segmented_topk_ref(x, k: int):
     """Segmented top-k oracle: x (S, C) -> ((S, k) f32 values,
     (S, k) int32 lane indices), descending per segment. Ties break to
-    the lowest lane (``lax.top_k`` semantics, matching the kernel's
-    iterative max-extract). ``-inf`` values mark exhausted segments;
-    their indices are not meaningful."""
+    the lowest lane (``lax.top_k`` semantics, which order ``-0.0``
+    below ``+0.0``). ``-inf`` values mark exhausted segments; they sit
+    at the segment's lowest ``-inf`` lanes."""
     k = int(min(k, x.shape[-1]))
     vals, idx = jax.lax.top_k(x.astype(jnp.float32), k)
     return vals, idx.astype(jnp.int32)

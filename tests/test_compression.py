@@ -49,6 +49,38 @@ def scale_bound(x, chunk):
     return np.asarray(per_elem) * 0.5 * (1 + 1e-5) + 1e-8
 
 
+def _magnitude_case(case):
+    """``(x (K, P) f32, k)`` for one edge of the top-k selection."""
+    rng = np.random.default_rng(len(case))
+    sign = rng.choice(np.array([-1.0, 1.0]), size=(3, 1000))
+    if case == "ties_straddle_tiles":
+        # 60 magnitudes above the k-th, then 300 ties at it over every
+        # 128-lane tile, with both signs
+        x = rng.uniform(0.0, 0.5, (3, 1000))
+        for row in x:
+            row[rng.choice(1000, 360, replace=False)] = np.concatenate(
+                [rng.uniform(2.0, 3.0, 60), np.ones(300)])
+        return jnp.asarray(x * sign, jnp.float32), 100
+    if case == "all_equal":
+        return jnp.asarray(sign[:2, :700] * 1.5, jnp.float32), 300
+    if case == "few_nonzero":
+        x = np.zeros((3, 500))
+        x[0, rng.choice(500, 20, replace=False)] = rng.normal(size=20)
+        x[1, 0] = -1.0
+        return jnp.asarray(x, jnp.float32), 64   # row 2 all zero
+    if case == "k_is_width":
+        return jnp.asarray(np.round(rng.normal(size=(2, 300)) * 2),
+                           jnp.float32), 300
+    if case == "k_not_whole_vregs":
+        return jnp.asarray(np.round(rng.normal(size=(5, 1000)) * 2),
+                           jnp.float32), 200
+    if case == "signed_zeros":
+        x = rng.choice(np.array([-0.0, 0.0, 1.0, -1.0]), size=(2, 400),
+                       p=[0.35, 0.35, 0.15, 0.15])
+        return jnp.asarray(x, jnp.float32), 150
+    raise ValueError(case)
+
+
 # ---------------------------------------------------------------------------
 # 1. kernels vs oracles
 # ---------------------------------------------------------------------------
@@ -75,6 +107,22 @@ class TestTopkSparsifyKernel:
         rvals, ridx = ref.topk_sparsify_ref(x, k)
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
         np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+
+    @pytest.mark.parametrize("case", ["ties_straddle_tiles", "all_equal",
+                                      "few_nonzero", "k_is_width",
+                                      "k_not_whole_vregs", "signed_zeros"])
+    def test_threshold_edge_cases_match_lax_topk(self, case):
+        """The selection's edges at block=128: ties at the k-th
+        magnitude split over tiles in lane order, a row of equal
+        magnitudes, fewer nonzero entries than k, k of the whole row or
+        of no whole number of vregs, and -0.0 beside +0.0."""
+        x, k = _magnitude_case(case)
+        vals, idx = topk_sparsify(x, k, block=128, interpret=True)
+        rvals, ridx = ref.topk_sparsify_ref(x, k)
+        np.testing.assert_array_equal(np.asarray(idx), np.asarray(ridx))
+        np.testing.assert_array_equal(np.asarray(vals), np.asarray(rvals))
+        np.testing.assert_array_equal(np.signbit(np.asarray(vals)),
+                                      np.signbit(np.asarray(rvals)))
 
     def test_tie_break_is_lowest_index(self):
         # constant-|x| rows: selection must be the first k lanes, in

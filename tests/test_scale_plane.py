@@ -39,6 +39,35 @@ def _churn(pool, rng, n_events):
 # segmented top-k kernel
 # ---------------------------------------------------------------------------
 
+def _threshold_case(case):
+    """``(keys (S, C) f32, k)`` for one edge of threshold select."""
+    rng = np.random.default_rng(len(case))
+    if case == "ties_straddle_tiles":
+        # 60 keys above the k-th, then 300 ties at it over every 128-lane
+        # tile: the quota of 40 ties is taken from the lowest lanes on
+        x = rng.uniform(-2.0, 0.5, (3, 1000))
+        for row in x:
+            row[rng.choice(1000, 360, replace=False)] = np.concatenate(
+                [rng.uniform(2.0, 3.0, 60), np.ones(300)])
+        return x.astype(np.float32), 100
+    if case == "all_equal":
+        return np.full((2, 700), 1.5, np.float32), 300
+    if case == "few_finite":
+        x = np.full((3, 500), -np.inf, np.float32)
+        x[0, rng.choice(500, 20, replace=False)] = rng.normal(size=20)
+        x[1, 0] = 1.0
+        return x, 64                             # row 2 all -inf
+    if case == "k_is_width":
+        return np.round(rng.normal(size=(2, 300)) * 2).astype(np.float32), 300
+    if case == "k_not_whole_vregs":
+        return np.round(rng.normal(size=(5, 1000)) * 2).astype(np.float32), 200
+    if case == "signed_zeros":
+        x = rng.choice(np.array([-0.0, 0.0, 1.0, -1.0], np.float32),
+                       size=(2, 400), p=[0.35, 0.35, 0.15, 0.15])
+        return x.astype(np.float32), 150
+    raise ValueError(case)
+
+
 class TestSegmentedTopk:
     @pytest.mark.parametrize("S,C,k", [(1, 8, 3), (4, 64, 8), (7, 129, 16),
                                        (3, 32, 32), (2, 16, 40)])
@@ -65,6 +94,23 @@ class TestSegmentedTopk:
         vo, io = ref.segmented_topk_ref(x, k)
         vk, ik = segmented_topk(x, k, block=128, interpret=True)
         np.testing.assert_array_equal(np.asarray(vk), np.asarray(vo))
+        np.testing.assert_array_equal(np.asarray(ik), np.asarray(io))
+
+    @pytest.mark.parametrize("case", ["ties_straddle_tiles", "all_equal",
+                                      "few_finite", "k_is_width",
+                                      "k_not_whole_vregs", "signed_zeros"])
+    def test_threshold_edge_cases_match_oracle(self, case):
+        """Threshold select's edges at block=128: the tie quota split
+        over tiles in lane order, a row that is one tie, shards with
+        fewer finite keys than k, k of the whole row or of no whole
+        number of vregs, and -0.0 beside +0.0 (top_k's total order)."""
+        from repro.kernels.segmented_topk import segmented_topk
+        x, k = _threshold_case(case)
+        vo, io = ref.segmented_topk_ref(x, k)
+        vk, ik = segmented_topk(x, k, block=128, interpret=True)
+        np.testing.assert_array_equal(np.asarray(vk), np.asarray(vo))
+        np.testing.assert_array_equal(np.signbit(np.asarray(vk)),
+                                      np.signbit(np.asarray(vo)))
         np.testing.assert_array_equal(np.asarray(ik), np.asarray(io))
 
     def test_ties_break_to_lowest_lane(self):

@@ -145,6 +145,16 @@ def test_forced_escalation_gives_two_passes(tmp_path, monkeypatch,
     assert stats["frontier_slots"] == stats["shards"] * (f1 + f2)
 
 
+def test_frontier_spans_name_the_selection_method(tmp_path, monkeypatch):
+    pool = _fleet(monkeypatch, 6000, seed=33)
+    sp = FLServiceProvider(pool)
+    _, events = _traced(tmp_path, lambda: sp.select_pools_batch(
+        _tasks([50.0, 800.0, 8000.0])))
+    fronts = [e for e in events if e[0] == "stage1.frontier"]
+    assert len(fronts) >= 3
+    assert {f[3]["method"] for f in fronts} == {"threshold"}
+
+
 def test_traced_selection_equals_untraced(tmp_path, monkeypatch):
     pool = _fleet(monkeypatch, 6000, seed=32)
     sp = FLServiceProvider(pool)

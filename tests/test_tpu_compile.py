@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench import kernels, trace
 from repro.kernels import compression, fedavg_agg, mkp_utility, segmented_topk
 from repro.models import cnn
 
@@ -72,6 +73,7 @@ def _compile_for_chip(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("K", [10, 20])
@@ -80,11 +82,22 @@ def test_fedavg_agg_quality(one_chip, cifar_p, x64, K):
                       ((K, cifar_p), jnp.float32), ((K,), jnp.float32))
 
 
-@pytest.mark.parametrize("k", [64, 4096])
+@pytest.mark.parametrize("k", [64, 2048, 4096, 8192])
 def test_segmented_topk_fleet_frontier(one_chip, x64, k):
     # 8 shards of the default 131072-row shard: a 1M-client mirror
-    _compile_for_chip(lambda r: segmented_topk.segmented_topk(r, k),
-                      one_chip, ((8, 131072), jnp.float32))
+    text = _compile_for_chip(lambda r: segmented_topk.segmented_topk(r, k),
+                             one_chip, ((8, 131072), jnp.float32))
+    # what the benchmark's roofline reader needs (bench/kernels.py): every
+    # custom call is a pass of the kernel, and the passes that write a
+    # 3-D f32 first output write one frontier width
+    calls = [trace.Event("", "", line.strip().removeprefix("ROOT "),
+                         0.0, 0.0, {})
+             for line in text.splitlines() if " custom-call(" in line]
+    assert calls
+    assert all(kernels.is_kernel(e, kernels.SEGMENTED_TOPK) for e in calls)
+    widths = {s[2] for e in calls for s in kernels.shapes(e)[:1]
+              if len(s) == 3}
+    assert widths == {-(-k // 128) * 128}
 
 
 def test_mkp_utility(one_chip, x64):
