@@ -18,11 +18,16 @@ It follows the served path's definition of a round:
   reports ``q_k = cos(delta_k, aggregate)`` for returned clients and the
   weighted mean loss.
 
-``dtype`` and ``precision`` select the arithmetic; the benchmark's
-control runs this same reference in bfloat16 at default precision.
-``fault`` plants one of the faults a timed path can have: ``"half"``
-trains on the first half of every batch, ``"flip"`` negates the first
-client's update where it is produced.
+``dtype`` and ``precision`` select the arithmetic. ``round_to`` (a
+dtype name, such as ``"float8_e4m3fn"``) rounds every input of every
+convolution and matrix product of the model to that type and back,
+scaled per tensor so that its largest magnitude lands on the type's
+largest finite value, as fp8 training scales; gradients pass the
+rounding unchanged. The benchmark's control is this reference in fp8
+at the default precision, one step below the program's single bfloat16
+pass. ``fault`` plants one of the faults a timed path can have:
+``"half"`` trains on the first half of every batch, ``"flip"`` negates
+the first client's update where it is produced.
 """
 from __future__ import annotations
 
@@ -56,26 +61,40 @@ def init_params(m: dict, seed32: int, dtype=jnp.float32):
     return jax.tree_util.tree_map(lambda x: x.astype(dtype), p)
 
 
-def forward(params, images, precision):
+def rounded(x, round_to):
+    """``x`` rounded to the type ``round_to`` and back, scaled per
+    tensor; the gradient passes unchanged. ``None`` leaves ``x`` as is."""
+    if round_to is None:
+        return x
+    top = float(jnp.finfo(round_to).max)
+    scale = jax.lax.stop_gradient(
+        jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / top)
+    q = (x / scale).astype(round_to).astype(x.dtype) * scale
+    return x + jax.lax.stop_gradient(q - x)
+
+
+def forward(params, images, precision, round_to=None):
     def block(x, p):
         y = jax.lax.conv_general_dilated(
-            x, p["w"], (1, 1), "SAME",
+            rounded(x, round_to), rounded(p["w"], round_to), (1, 1), "SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
         y = jax.nn.relu(y + p["b"])
         return jax.lax.reduce_window(y, -jnp.inf, jax.lax.max,
                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
 
+    def dense(x, p):
+        return jnp.dot(rounded(x, round_to), rounded(p["w"], round_to),
+                       precision=precision) + p["b"]
+
     x = block(images.astype(params["conv1"]["w"].dtype), params["conv1"])
     x = block(x, params["conv2"])
     x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, params["fc1"]["w"], precision=precision)
-                    + params["fc1"]["b"])
-    return jnp.dot(x, params["fc2"]["w"], precision=precision) \
-        + params["fc2"]["b"]
+    x = jax.nn.relu(dense(x, params["fc1"]))
+    return dense(x, params["fc2"])
 
 
-def loss(params, images, labels, precision):
-    logp = jax.nn.log_softmax(forward(params, images, precision)
+def loss(params, images, labels, precision, round_to=None):
+    logp = jax.nn.log_softmax(forward(params, images, precision, round_to)
                               .astype(jnp.float32))
     return -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0].mean()
 
@@ -94,9 +113,10 @@ def draws(seed32: int, rnd: int, slots: int, steps: int, batch: int):
 
 
 @functools.partial(jax.jit, static_argnames=("lr", "server_lr", "steps",
-                                             "precision", "fault"))
+                                             "precision", "round_to",
+                                             "fault"))
 def _round(params, images, labels, weights, keep, *, lr, server_lr, steps,
-           precision, fault):
+           precision, round_to, fault):
     """One round over ``K`` client slots: images ``(K, steps, b, H, W,
     C)``, labels ``(K, steps, b)``, weights and keep ``(K,)``."""
     if fault == "half":
@@ -106,7 +126,8 @@ def _round(params, images, labels, weights, keep, *, lr, server_lr, steps,
     def client(imgs, labs):
         p, losses = params, []
         for e in range(steps):
-            v, g = jax.value_and_grad(loss)(p, imgs[e], labs[e], precision)
+            v, g = jax.value_and_grad(loss)(p, imgs[e], labs[e], precision,
+                                            round_to)
             p = jax.tree_util.tree_map(lambda a, b: a - lr * b, p, g)
             losses.append(v)
         delta = jax.tree_util.tree_map(lambda a, b: a - b, params, p)
@@ -137,7 +158,7 @@ def _round(params, images, labels, weights, keep, *, lr, server_lr, steps,
 def replay(m: dict, t: dict, seed32: int, images, labels: np.ndarray,
            parts: list[np.ndarray], rounds: list[tuple[int, list[int]]],
            slots: int, *, dtype=jnp.float32, precision=HIGHEST,
-           fault: str | None = None):
+           round_to: str | None = None, fault: str | None = None):
     """Run ``rounds`` (``(round index, client ids)``, consecutive, from
     the initial weights) and return ``(params after them, [(keep (k,),
     q (k,), loss) per round])``, every value on the host in float32.
@@ -170,7 +191,8 @@ def replay(m: dict, t: dict, seed32: int, images, labels: np.ndarray,
         params, q, lv = _round(params, imgs, labs, jnp.asarray(w),
                                jnp.asarray(kp), lr=t["local_lr"],
                                server_lr=t["server_lr"], steps=steps,
-                               precision=precision, fault=fault)
+                               precision=precision, round_to=round_to,
+                               fault=fault)
         out.append((keep, np.asarray(q, np.float32)[:k], float(lv)))
     host = jax.tree_util.tree_map(lambda x: np.asarray(x, np.float32),
                                   params)

@@ -2,8 +2,9 @@
 
 The generators are copies of the program's own (``ClientPoolState.random``
 with ``criteria.random_histograms``, ``data.synthetic.make_classification_data``
-and ``fl.partition.partition_labels``), kept here so that a change to the
-program cannot move the benchmark's inputs. The image generator is
+and, at a shape the configuration fixes, ``fl.partition.partition_labels``),
+kept here so that a change to the program cannot move the benchmark's
+inputs. The image generator is
 vectorised; its draws differ from the host original, its distribution
 does not.
 """
@@ -105,41 +106,30 @@ def cifar_images(labels: np.ndarray, g: np.random.Generator,
     return imgs
 
 
-def partition_type2(labels: np.ndarray, n_clients: int, classes: int,
-                    g: np.random.Generator) -> list[np.ndarray]:
-    """The paper's type-2 non-iid split: each client draws
-    ``len(labels) // n_clients`` samples, 90% from one class and 10% from
-    another, without replacement from per-class pools that recycle when
-    exhausted."""
-    by_class = [np.flatnonzero(labels == c) for c in range(classes)]
-    for c in range(classes):
-        g.shuffle(by_class[c])
-    cursors = [0] * classes
-    spc = len(labels) // n_clients
-
-    def draw(c, k):
-        pool = by_class[c]
-        out = []
-        while k > 0:
-            take = min(k, len(pool) - cursors[c])
-            if take <= 0:
-                cursors[c] = 0
-                g.shuffle(pool)
-                continue
-            out.append(pool[cursors[c]:cursors[c] + take])
-            cursors[c] += take
-            k -= take
-        return np.concatenate(out)
-
-    ratios = np.array([0.9, 0.1])
-    parts = []
-    for _ in range(n_clients):
-        cls = g.choice(classes, size=len(ratios), replace=False)
-        counts = np.maximum((ratios * spc).astype(int), 1)
-        idx = np.concatenate([draw(c, k) for c, k in zip(cls, counts)])
-        g.shuffle(idx)
-        parts.append(idx)
-    return parts
+def partition_fixed(client_classes: list[list[int]], split: list[float],
+                    n_samples: int, g: np.random.Generator
+                    ) -> tuple[np.ndarray, list[np.ndarray]]:
+    """The paper's type-2 non-iid split at a shape the configuration
+    fixes: client ``j`` holds ``n_samples // len(client_classes)``
+    samples, ``split[i]`` of them of class ``client_classes[j][i]``.
+    Returns ``(labels (n_samples,), parts)``. The seed draws values
+    only: which dataset indices each client holds, in which order. Every
+    seed gives every client the same class counts, so stage 2 (which
+    breaks ties by client id and by class index) makes the same subsets
+    every time."""
+    per = n_samples // len(client_classes)
+    counts = [max(int(r * per), 1) for r in split]
+    if sum(counts) != per or per * len(client_classes) != n_samples:
+        raise ValueError(f"{n_samples} samples do not split into "
+                         f"{len(client_classes)} clients of {counts}")
+    labels = np.concatenate([np.repeat(np.asarray(cls, np.int32), counts)
+                             for cls in client_classes])
+    order = g.permutation(n_samples)
+    out = np.empty(n_samples, np.int32)
+    out[order] = labels
+    parts = [order[j * per:(j + 1) * per]
+             for j in range(len(client_classes))]
+    return out, parts
 
 
 def client_criteria(parts: list[np.ndarray], labels: np.ndarray,

@@ -76,14 +76,22 @@ def applies(metric: dict, cell: str, reported: set[str] | None = None
 
 class CompileClock:
     """Counts JAX's compile requests and persistent-cache hits, and sums
-    backend compile seconds, so a window can show that nothing compiled
-    inside it."""
+    the seconds spent tracing, lowering, compiling and reading compiled
+    programs from the cache, so a window can show that nothing compiled
+    inside it and set-up can show where its time went."""
+
+    DURATIONS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+                 "/jax/core/compile/jaxpr_to_mlir_module_duration":
+                     "lower_s",
+                 "/jax/core/compile/backend_compile_duration": "compile_s",
+                 "/jax/compilation_cache/cache_retrieval_time_sec":
+                     "cache_read_s"}
 
     def __init__(self):
         import jax
         self.requests = 0
         self.cache_hits = 0
-        self.compile_s = 0.0
+        self.seconds = dict.fromkeys(self.DURATIONS.values(), 0.0)
         jax.monitoring.register_event_listener(self._on_event)
         jax.monitoring.register_event_duration_secs_listener(self._on_dur)
 
@@ -94,11 +102,12 @@ class CompileClock:
             self.cache_hits += 1
 
     def _on_dur(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
+        if event in self.DURATIONS:
+            self.seconds[self.DURATIONS[event]] += duration
 
-    def snapshot(self) -> tuple[int, int, float]:
-        return self.requests, self.cache_hits, self.compile_s
+    def snapshot(self) -> tuple[int, int, float, dict]:
+        return (self.requests, self.cache_hits, self.seconds["compile_s"],
+                dict(self.seconds))
 
 
 class Session:
@@ -222,6 +231,8 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             "compile_requests_in_setup": before[0],
             "cache_hits_in_setup": before[1],
             "backend_compile_s_in_setup": before[2],
+            **{f"{k}_in_setup": v for k, v in before[3].items()
+               if k != "compile_s"},
             "compile_requests_in_window": window_compiles,
             "samples": res.get("samples", {}),
             **res.get("info", {})}})
@@ -243,8 +254,9 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
         ctx = types.SimpleNamespace(
             events=events, counters=res.get("counters", {}),
             peaks=peaks, config=config, traffic=traffic,
-            window_s=trace_mod.window_s(events),
-            busy_s=trace_mod.busy_s(events))
+            chips=len(devices), window_s=trace_mod.window_s(events),
+            busy_s=trace_mod.busy_s(events),
+            device_busy_s=trace_mod.device_busy_s(events, len(devices)))
         for m in spec["per_layer"]:
             if not applies(m, workload, reported_e2e):
                 continue
@@ -253,7 +265,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
             value = reader.read(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-        device["busy_s"] = ctx.busy_s
+        device["busy_s"] = sum(ctx.device_busy_s) / len(devices)
         device["window_s"] = ctx.window_s
         breakdown = trace_mod.breakdown(events)
 

@@ -4,10 +4,15 @@ a closed loop (each tenant's next chunk is dispatched when its previous
 one is collected).
 
 Set-up makes the data and the client pool from the seed, submits every
-tenant, warms each tenant's round-chunk program for every (rounds per
-segment, padded client count) its schedules can ask for, and serves
-sweeps until every tenant is past its first period. The window then
-calls ``ServiceScheduler.sweep`` until it closes.
+tenant and serves sweeps until every tenant has finished the periods
+in which the evaluation rounds (every ``eval_every`` rounds) fall at
+each phase they take within a period: one, where a period's rounds are
+a multiple of ``eval_every``. Every period schedules the same subsets
+(the configuration fixes the partition's shape, and no client is
+suspended), so those periods meet every (rounds per segment, padded
+client count) that the round-chunk programs are called with, on each
+tenant's own chip: the window compiles nothing. The window then calls
+``ServiceScheduler.sweep`` until it closes.
 
 Correctness: the first chunk of every tenant (set-up's first sweep, the
 window's own call) is replayed by the configuration's plain reference
@@ -16,6 +21,7 @@ paper's §VII guarantees.
 """
 from __future__ import annotations
 
+import math
 import time
 
 import numpy as np
@@ -23,16 +29,20 @@ import numpy as np
 from bench import datagen, harness
 from bench.harness import BENCH
 
+
 def p95(values) -> float:
+    """95th percentile; NaN where there is no sample."""
+    if not len(values):
+        return float("nan")
     return float(np.percentile(np.asarray(values, np.float64), 95))
 
 
-def leaf_gap(prog: dict, ref: dict, base_p: dict, base_r: dict):
-    """Worst leaf's gap between the program's and the reference's norm
+def leaf_gaps(prog: dict, ref: dict, base_p: dict, base_r: dict):
+    """Per leaf, the gap between the program's and the reference's norm
     of the parameters' change, against the larger of that leaf's
     reference norm and the median leaf's. Leaves whose reference change
-    is under a thousandth of the median leaf's are left out: they move
-    by round-off alone."""
+    is under a thousandth of the median leaf's read NaN: they move by
+    round-off alone."""
     import jax
     pl = jax.tree_util.tree_leaves(prog)
     rl = jax.tree_util.tree_leaves(ref)
@@ -43,9 +53,8 @@ def leaf_gap(prog: dict, ref: dict, base_p: dict, base_r: dict):
     rn = [float(np.linalg.norm((a - b).astype(np.float64)))
           for a, b in zip(rl, br)]
     med = float(np.median(rn))
-    gaps = [abs(p - r) / max(r, med) for p, r in zip(pn, rn)
-            if r >= 1e-3 * med]
-    return max(gaps) if gaps else float("nan")
+    return [abs(p - r) / max(r, med) if r >= 1e-3 * med else float("nan")
+            for p, r in zip(pn, rn)]
 
 
 def schedule_violations(pool_ids, subsets, x_star: int) -> int:
@@ -93,18 +102,21 @@ class Cell:
 
     # -- set-up -------------------------------------------------------------
     def setup(self) -> None:
-        import jax
-        import jax.numpy as jnp
         self.build_tenants()
         self._sweep_setup()                 # stage 1, placement, chunk 1
-        self._warm(jax, jnp)
-        while any(self.scheduler.state(tid).period < 1
+        rounds = max(self.first_period.values())
+        periods = math.lcm(rounds, self.t["eval_every"]) // rounds
+        while any(self.scheduler.state(tid).period < periods
                   for tid in self.tids):
             self._sweep_setup()
 
     def build_tenants(self) -> None:
         """Data, client pool, provider, scheduler, and every tenant's
-        trainer and task, submitted."""
+        trainer and task, submitted. Every trainer is built on one
+        staged copy of the images; with ``devices`` over 1 in the
+        traffic mix the scheduler places the tenants by its
+        ``placement`` policy, and each trainer's ``place_on`` moves its
+        state and data to its chip."""
         import jax
         from repro.core import (ClientPoolState, FLServiceProvider,
                                 ServiceScheduler, TaskRequest)
@@ -116,29 +128,33 @@ class Cell:
         pc = cfg["pool"]
         classes = m["num_classes"]
         n_train, n_test = pc["train_samples"], pc["test_samples"]
-        g = datagen.rng(seed, "labels")
-        labels = g.integers(0, classes, size=n_train + n_test).astype(
-            np.int32)
+        labels, parts = datagen.partition_fixed(
+            pc["client_classes"], pc["class_split"], n_train,
+            datagen.rng(seed, "split"))
+        test_labels = datagen.rng(seed, "test-labels").integers(
+            0, classes, size=n_test).astype(np.int32)
         shape = dict(height=m["height"], width=m["width"],
                      channels=m["channels"], classes=classes)
-        # staged once and shared: every tenant trains on the same clients'
-        # data, as tasks of one service over one client pool do
-        train_img = jax.device_put(datagen.cifar_images(
-            labels[:n_train], datagen.rng(seed, "train-images"), **shape),
-            self.session.devices[0])
-        test_img = jax.device_put(datagen.cifar_images(
-            labels[n_train:], datagen.rng(seed, "test-images"), **shape),
-            self.session.devices[0])
-        parts = datagen.partition_type2(labels[:n_train], pc["clients"],
-                                        classes, datagen.rng(seed, "split"))
+        host_img = datagen.cifar_images(
+            labels, datagen.rng(seed, "train-images"), **shape)
+        host_test = datagen.cifar_images(
+            test_labels, datagen.rng(seed, "test-images"), **shape)
+        n_dev = int(self.traffic.get("devices", 1))
+        devices = self.session.devices[:n_dev]
+        if len(devices) < n_dev:
+            raise ValueError(f"the traffic places tenants on {n_dev} "
+                             f"devices, the cell has {len(devices)}")
+        # staged once: every tenant trains on the same clients' data, as
+        # tasks of one service over one pool do
+        self.images = jax.device_put(host_img, devices[0])
+        data = ClassificationData(self.images, labels, classes)
+        test_img = jax.device_put(host_test, devices[0])
         scores, hists, costs = datagen.client_criteria(
-            parts, labels[:n_train], classes, datagen.rng(seed, "criteria"))
-        self.labels, self.parts, self.images = labels[:n_train], parts, \
-            train_img
+            parts, labels, classes, datagen.rng(seed, "criteria"))
+        self.labels, self.parts = labels, parts
         pool = ClientPoolState(np.arange(pc["clients"], dtype=np.int64),
                                scores, hists, costs)
-        data = ClassificationData(train_img, labels[:n_train], classes)
-        test = ClassificationData(test_img, labels[n_train:], classes)
+        test = ClassificationData(test_img, test_labels, classes)
 
         schedules = self.schedules = []
         first = self.first_period = {}      # task -> rounds in period 0
@@ -163,7 +179,8 @@ class Cell:
                           dtype=m["dtype"])
         self.scheduler = ServiceScheduler(
             RecordingProvider(pool),
-            max_inflight=self.traffic["max_inflight"])
+            max_inflight=self.traffic["max_inflight"], n_devices=n_dev,
+            placement=self.traffic.get("placement"))
         self.sims, self.tids, self.seeds = [], [], []
         self.w0, self.w_first, self.n_first = [], {}, {}
         chunk = t["round_chunk"]
@@ -182,6 +199,7 @@ class Cell:
                 budget=float(cfg["budget"]), n_star=pc["clients"],
                 subset_size=t["subset_size"],
                 subset_delta=t["subset_delta"], x_star=t["x_star"],
+                rep_threshold=t["rep_threshold"],
                 max_periods=10**9, round_chunk=chunk, max_rounds=10**9,
                 seed=datagen.sub_seed(seed, "task", i))
             tid = self.scheduler.submit(
@@ -209,28 +227,6 @@ class Cell:
     def _sweep_setup(self) -> None:
         self.scheduler.sweep()
         self.last_return = {tid: time.perf_counter() for tid in self.tids}
-
-    def _warm(self, jax, jnp) -> None:
-        """Compile every tenant's chunk program for every (rounds per
-        segment, padded client count) a schedule can ask for, on dummy
-        weights on the tenant's own device."""
-        t = self.t
-        sizes = range(t["subset_size"] - t["subset_delta"],
-                      t["subset_size"] + t["subset_delta"] + 1)
-        for sim in self.sims:
-            dev = next(iter(jax.tree_util.tree_leaves(sim.params)[0]
-                            .devices()))
-            dummy = jax.device_put(
-                jax.tree_util.tree_map(jnp.zeros_like, sim.params), dev)
-            for s in range(1, t["round_chunk"] + 1):
-                for k in sorted({sim._k_pad(k) for k in sizes}):
-                    sched = {"rows": jnp.zeros((s, k), jnp.int32),
-                             "weights": jnp.zeros((s, k), jnp.float32),
-                             "active": jnp.zeros((s, k), jnp.float32),
-                             "round_ids": jnp.arange(s, dtype=jnp.int32)}
-                    dummy, info = sim.chunk_fn(dummy, sim.data, sched,
-                                               sim.base_key)
-            jax.block_until_ready(dummy)
 
     # -- window ---------------------------------------------------------------
     def window(self, seconds: float) -> dict:
@@ -272,13 +268,27 @@ class Cell:
             "samples": {"round_p95_ms": len(intervals),
                         "client_updates": updates, "rounds": rounds},
             "info": {"sweeps_in_window": sweeps,
-                     "scheduled_client_slots": slots},
+                     "scheduled_client_slots": slots,
+                     "tenants_per_device": self.tenants_per_device(),
+                     "subset_sizes_per_period": self.period_shapes()},
             "counters": {
                 "rounds": rounds, "updates": updates, "seconds": seconds,
                 "train_flops_per_update": t["local_steps"] * t["batch_size"]
                 * counts.cnn_train_flops_per_sample(m),
                 "params": counts.cnn_params(m)},
         }
+
+    def tenants_per_device(self) -> list[int]:
+        placed = self.scheduler.placements()
+        return [sum(placed[tid] == d for tid in self.tids)
+                for d in range(self.scheduler.n_devices)]
+
+    def period_shapes(self) -> list[list[int]]:
+        """Every distinct list of subset sizes that stage 2 made for a
+        period: one, where the configuration fixes the work."""
+        return [list(x) for x in sorted({tuple(len(s) for s in subsets)
+                                         for _, _, subsets in
+                                         self.schedules})]
 
     def release(self) -> None:
         import jax
@@ -323,36 +333,60 @@ class Cell:
                             keep, q, lv) in zip(f["rounds"], rounds)]})
         return out
 
+    def control_first_chunks(self, first: list[dict]) -> list[dict]:
+        """The control: the plain reference one precision step below
+        the program's (every model product's inputs in fp8, at the
+        default precision), replaying the same rounds."""
+        import jax
+        return self.reference_first_chunks(
+            first, precision=jax.lax.Precision.DEFAULT,
+            round_to=self.config["check"]["control_round_to"])
+
     # -- check ----------------------------------------------------------------
     def check(self) -> list[tuple[str, float, float]]:
         limits = self.config["check"]["limits"]
         got = compare(self.first, self.reference_first_chunks(self.first))
         violations = sum(schedule_violations(p, s, x)
                          for p, x, s in self.schedules)
-        return [("loss_gap", got["loss_gap"], limits["loss_gap"]),
-                ("update_gap", got["update_gap"], limits["update_gap"]),
-                ("q_gap", got["q_gap"], limits["q_gap"]),
+        return [*((n, got[n], float(lim)) for n, lim in limits.items()),
                 ("dropout_mismatch", got["dropout_mismatch"], 0.0),
                 ("schedule_violations", float(violations), 0.0),
                 ("nonfinite_losses", float(self.nonfinite), 0.0)]
 
 
+def tenant_gaps(g: dict, w: dict) -> dict:
+    """One tenant's first chunk against the reference's: each round's
+    relative loss gap, each round's worst client quality gap, each
+    leaf's gap in the norm of the weights' change (``leaf_gaps``), and
+    the returned flags that differ."""
+    loss, q, mismatched = [], [], 0
+    for (_, _, lg, bg, qg), (_, _, lw, bw, qw) in zip(g["rounds"],
+                                                       w["rounds"]):
+        loss.append(abs(lg - lw) / abs(lw))
+        q.append(float(np.max(np.abs(qg - qw))))
+        mismatched += int((np.asarray(bg) != np.asarray(bw)).sum())
+    return {"loss": loss, "q": q,
+            "update": leaf_gaps(g["w1"], w["w1"], g["w0"], w["w0"]),
+            "dropout_mismatch": mismatched}
+
+
 def compare(got: list[dict], want: list[dict]) -> dict:
-    """The numbers compared, over every tenant's first chunk: the worst
-    round's relative loss gap, the worst leaf's gap in the norm of the
-    weights' change over the chunk (``leaf_gap``), the worst client's
-    quality gap, and the returned flags that differ."""
-    loss_gap = q_gap = upd_gap = 0.0
-    mismatched = 0
-    for g, w in zip(got, want):
-        for (_, _, lg, bg, qg), (_, _, lw, bw, qw) in zip(g["rounds"],
-                                                           w["rounds"]):
-            loss_gap = max(loss_gap, abs(lg - lw) / abs(lw))
-            mismatched += int((np.asarray(bg) != np.asarray(bw)).sum())
-            q_gap = max(q_gap, float(np.max(np.abs(qg - qw))))
-        upd_gap = max(upd_gap, leaf_gap(g["w1"], w["w1"], g["w0"], w["w0"]))
-    return {"loss_gap": loss_gap, "update_gap": upd_gap, "q_gap": q_gap,
-            "dropout_mismatch": float(mismatched)}
+    """The numbers compared, over every tenant's first chunk
+    (``tenant_gaps``): the worst round's relative loss gap and the worst
+    leaf's gap in the norm of the weights' change, over all tenants
+    (``loss_gap``, ``update_gap``) and, for the weights, the median
+    tenant's (``update_gap_med``); the worst client quality gap of the
+    first round (``q_gap_first``); and the returned flags that differ.
+    The medians and the first round are steady from seed to seed, where
+    the worst tenant's later rounds swing (PERF.md §2)."""
+    per = [tenant_gaps(g, w) for g, w in zip(got, want)]
+    upd = [float(np.nanmax(t["update"])) for t in per]
+    return {"loss_gap": max(max(t["loss"]) for t in per),
+            "update_gap": max(upd),
+            "update_gap_med": float(np.median(upd)),
+            "q_gap_first": max(t["q"][0] for t in per),
+            "dropout_mismatch": float(sum(t["dropout_mismatch"]
+                                          for t in per))}
 
 
 def build(config: dict, traffic: dict, seed: int, session) -> Cell:
@@ -364,11 +398,11 @@ def readings(config: dict, traffic: dict, seeds: list[int], n_control: int,
     """Per seed, one row of the numbers compared: every tenant's first
     chunk, driven through ``ServiceScheduler.sweep`` as set-up drives it,
     against the plain reference; on the first ``n_control`` seeds also
-    the control (the reference in bfloat16 at default precision, in the
-    program's place) and each fault planted in the reference, through
-    the same comparison. ``seconds`` is unused: no window is needed."""
+    the control (``Cell.control_first_chunks``, in the program's place)
+    and each fault planted in the reference, through the same
+    comparison, with each tenant's gaps (``tenant_gaps``) beside the
+    numbers. ``seconds`` is unused: no window is needed."""
     import jax
-    import jax.numpy as jnp
     for n, s in enumerate(seeds):
         t = time.perf_counter()
         cell = build(config, traffic, s, session)
@@ -379,15 +413,15 @@ def readings(config: dict, traffic: dict, seeds: list[int], n_control: int,
         first = cell.program_first_chunks()
         cell.scheduler = cell.sims = None
         ref = cell.reference_first_chunks(first)
-        row = {"seed": s, "program": compare(first, ref),
-               "losses": [[r[2] for r in f["rounds"]] for f in first],
-               "ref_losses": [[r[2] for r in f["rounds"]] for f in ref]}
+        runs = {"program": first}
         if n < n_control:
-            row["control"] = compare(cell.reference_first_chunks(
-                first, dtype=jnp.bfloat16,
-                precision=jax.lax.Precision.DEFAULT), ref)
+            runs["control"] = cell.control_first_chunks(first)
             for fault in ("half", "flip"):
-                row[fault] = compare(
-                    cell.reference_first_chunks(first, fault=fault), ref)
+                runs[fault] = cell.reference_first_chunks(first, fault=fault)
+        row = {"seed": s}
+        for name, got in runs.items():
+            row[name] = compare(got, ref)
+            row[name]["tenants"] = [tenant_gaps(g, w)
+                                    for g, w in zip(got, ref)]
         row["seconds"] = time.perf_counter() - t
         yield row

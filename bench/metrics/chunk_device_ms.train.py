@@ -1,5 +1,6 @@
 """Device time of the round-chunk program (``DeviceFLSim``'s jitted
-``chunk_fn``) in the traced window, per round trained in it."""
+``chunk_fn``) in the traced window, summed over the chips, per round
+trained in it."""
 from bench import trace
 
 PROGRAM = "jit_chunk_fn"

@@ -1,9 +1,11 @@
-"""Share of the traced window in which no operation ran on the device,
-in the training cells: one minus the busy union over the window."""
-from bench import trace
+"""Share of the traced window in which no operation ran on a chip, in
+the training cells: each chip's own idle share (one minus its busy
+union over the window), averaged over the chips the cell uses. An idle
+chip counts in full; a union over the chips would hide it."""
 
 
 def read(ctx):
-    if not trace.device_planes(ctx.events) or ctx.window_s <= 0:
+    if ctx.window_s <= 0 or not any(ctx.device_busy_s):
         return None
-    return 100.0 * (1.0 - ctx.busy_s / ctx.window_s)
+    return 100.0 * sum(1.0 - b / ctx.window_s
+                       for b in ctx.device_busy_s) / len(ctx.device_busy_s)

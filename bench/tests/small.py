@@ -19,7 +19,8 @@ def small_config(name: str) -> dict:
         cfg["model"].update(height=16, width=16, conv1=4, conv2=8,
                             hidden=16)
         cfg["pool"].update(clients=12, train_samples=1200,
-                           test_samples=64)
+                           test_samples=64,
+                           client_classes=cfg["pool"]["client_classes"][:12])
         cfg["training"].update(subset_size=4, subset_delta=1,
                                pad_subset_to=5, batch_size=4)
     elif name == "fleet-1m":
@@ -38,7 +39,7 @@ def hierarchical_at_small_size(monkeypatch) -> None:
 def small_traffic(name: str) -> dict:
     tr = copy.deepcopy(harness.read_json(
         ROOT / "bench" / "traffic" / f"{name}.json"))
-    if tr["kind"] == "closed_tenants":
+    if tr["kind"] == "closed_tenants" and tr.get("devices", 1) == 1:
         tr["tenants"] = 2
     if tr["kind"] == "open_select":
         tr.update(rate_per_s=20.0, warm_budgets=4)
@@ -52,13 +53,14 @@ SPEC = ROOT / "bench" / "tests" / "data" / "two_cells.json"
 
 def run_small(workload: str, seed: int = 123, seconds: float = 1.0,
               trace: bool = False, config: dict | None = None,
-              traffic: dict | None = None, on_trace=None) -> dict:
-    spec = harness.read_json(SPEC)
+              traffic: dict | None = None, on_trace=None,
+              spec_file=SPEC) -> dict:
+    spec = harness.read_json(spec_file)
     cell = harness.entry(spec["workloads"], workload, "workload")
     err = io.StringIO()
     line = harness.run_cell(
         ROOT, workload, seed, seconds, trace, t0=time.perf_counter(),
-        spec_file=SPEC,
+        spec_file=spec_file,
         require_tpu=False, err=err,
         config=config or small_config(cell["config"]),
         traffic=traffic or small_traffic(cell["traffic"]),

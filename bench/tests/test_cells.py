@@ -96,7 +96,8 @@ def test_cifar_altered_update_is_caught(monkeypatch):
     monkeypatch.setattr(ops, "fedavg_agg_quality", altered)
     line = small.run_small("cifar-dense", seed=13)
     assert not line["correct"]
-    assert {"update_gap", "q_gap"} & set(failed_checks(line))
+    assert {"update_gap", "update_gap_med", "q_gap_first"} & set(
+        failed_checks(line))
 
 
 def test_cifar_bfloat16_control_fails_the_run(monkeypatch):
@@ -118,7 +119,8 @@ def test_cifar_bfloat16_control_fails_the_run(monkeypatch):
     monkeypatch.setattr(harness, "load_module", load)
     line = small.run_small("cifar-dense", seed=14)
     assert not line["correct"]
-    assert {"loss_gap", "update_gap", "q_gap"} & set(failed_checks(line))
+    assert {"loss_gap", "update_gap", "update_gap_med", "q_gap_first"} & set(
+        failed_checks(line))
 
 
 # -- stage-1 faults ------------------------------------------------------------

@@ -71,13 +71,16 @@ def test_metric_readers_on_the_small_trace():
                               "hbm_bytes_per_s": 819e9},
         counters={"rounds": 3, "updates": 30, "seconds": 10.0,
                   "train_flops_per_update": 1e9, "tasks": 2},
-        window_s=trace.window_s(events), busy_s=trace.busy_s(events))
+        chips=1, window_s=trace.window_s(events),
+        busy_s=trace.busy_s(events),
+        device_busy_s=trace.device_busy_s(events, 1))
     read = {}
     for name in ("idle_share.train", "chunk_device_ms.train", "train_mfu",
-                 "fedavg_agg_roofline"):
+                 "fedavg_agg_roofline", "train_device_idle_max"):
         read[name] = harness.load_module(
             harness.BENCH / "metrics" / f"{name}.py", name).read(ctx)
     assert read["idle_share.train"] == pytest.approx(75.0)
+    assert read["train_device_idle_max"] == pytest.approx(75.0)
     assert read["chunk_device_ms.train"] == pytest.approx(1e-4)
     assert read["train_mfu"] == pytest.approx(100 * 3e9 / 197e12)
     least = 59_964_624 / 819e9
@@ -88,10 +91,12 @@ def test_metric_readers_on_the_small_trace():
 def test_a_reader_that_finds_nothing_returns_nothing():
     events = [ev(HOST, "python", "bench.window", 0, 1000)]
     ctx = types.SimpleNamespace(events=events, peaks=None, counters={},
-                                window_s=1e-6, busy_s=0.0)
+                                chips=4, window_s=1e-6, busy_s=0.0,
+                                device_busy_s=trace.device_busy_s(events, 4))
     for name in ("idle_share.select", "select_call_ms", "select_wait_p95_ms",
                  "segmented_topk_roofline", "fedavg_agg_roofline",
-                 "chunk_device_ms.train", "train_mfu"):
+                 "chunk_device_ms.train", "train_mfu", "idle_share.train",
+                 "train_device_idle_max"):
         mod = harness.load_module(harness.BENCH / "metrics" / f"{name}.py",
                                   name)
         assert mod.read(ctx) is None, name

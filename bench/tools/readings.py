@@ -1,7 +1,7 @@
 """The readings the correctness limits are set from, on the chip at the
 cells' own sizes, many seeds in one process:
 
-    python bench/tools/readings.py --workload cifar-dense --seeds 12 --control 3
+    python bench/tools/readings.py --workload cifar-tenants --seeds 12 --control 12
     python bench/tools/readings.py --workload fleet-select --seeds 12 --control 3
 
 Per seed it prints one JSON line, as the traffic kind's ``readings``

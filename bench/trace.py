@@ -139,6 +139,20 @@ def busy_s(events: list[Event]) -> float:
     return sum(per) / len(per) if per else 0.0
 
 
+def device_busy_s(events: list[Event], chips: int) -> list[float]:
+    """Per chip the cell uses, the seconds in which an operation ran on
+    it inside the window: one value for each of ``chips``, in plane
+    order. A chip whose plane holds no operation reads 0, so an idle
+    chip counts as idle rather than dropping out of an average."""
+    lo, hi = window(events)
+    per = []
+    for p in device_planes(events)[:chips]:
+        iv = union(clip(((e.start_ns, e.end_ns)
+                         for e in device_ops(events, p)), lo, hi))
+        per.append(sum(e - s for s, e in iv) * 1e-9)
+    return per + [0.0] * (chips - len(per))
+
+
 def window_s(events: list[Event]) -> float:
     lo, hi = window(events)
     return (hi - lo) * 1e-9
